@@ -84,7 +84,7 @@ def _bench5_baseline() -> tuple[float, str]:
     return BASELINE_PN16_UGAL0_SECONDS, "recorded"
 
 
-def _points_demand(q: int):
+def points_demand(q: int):
     """All sources -> every point of PG(2, q): the transitive-orbit
     demand whose saturation knee is globally sharp (module docstring)."""
     g = pn_graph(q)
@@ -202,7 +202,7 @@ def pn27_ugal(steps: int = 30) -> tuple[dict, float]:
     the dense layout trips SIM_MAX_CELLS and ``auto`` escalates to the
     fused backend; the per-VC dest compaction (757 point columns of
     1514) is what lets the sweep run at all (module docstring)."""
-    g, dem = _points_demand(27)
+    g, dem = points_demand(27)
     cells = g.n * g.max_degree * g.n
     assert cells > SIM_MAX_CELLS  # dense layout must be infeasible
     ref = saturation_report(g, dem, routing="ugal")
@@ -233,7 +233,7 @@ def pn27_sweep() -> tuple[dict, float]:
     runs before backend selection, so ``auto`` sizes from the
     post-shrink cells (32.1M < SIM_MAX_CELLS) and would pick jax; this
     row exists to time the fused path at scale (module docstring)."""
-    g, dem = _points_demand(27)
+    g, dem = points_demand(27)
     cells = g.n * g.max_degree * g.n
     assert cells > SIM_MAX_CELLS  # the row exists to cross the cap
     ref = saturation_report(g, dem, routing="minimal")

@@ -98,6 +98,8 @@ def main(argv=None) -> None:
                          "while the run is going; tail -f it to watch a "
                          "long benchmark instead of waiting for the JSON")
     args = ap.parse_args(argv)
+    from repro.jaxenv import enable_compile_cache
+    enable_compile_cache()
 
     records: list[dict] = []
     errors: list[dict] = []

@@ -688,26 +688,14 @@ def _loads_csr(g: Graph, sources: np.ndarray, targets_mask: np.ndarray,
 # ---------------------------------------------------------------------------
 
 
-def _jax_available() -> bool:
-    try:
-        import jax  # noqa: F401
-
-        return True
-    except ImportError:
-        return False
-
-
 def _loads_jax(g: Graph, sources: np.ndarray, targets_mask: np.ndarray,
                demand: np.ndarray | None = None):
     import jax
     import jax.numpy as jnp
 
-    old_x64 = jax.config.jax_enable_x64
-    jax.config.update("jax_enable_x64", True)
-    try:
+    from ..jaxenv import x64
+    with x64():
         return _loads_jax_x64(g, sources, targets_mask, jax, jnp, demand)
-    finally:
-        jax.config.update("jax_enable_x64", old_x64)
 
 
 def _loads_jax_x64(g: Graph, sources, targets_mask, jax, jnp, demand=None):
@@ -804,20 +792,21 @@ def _loads_pallas(g: Graph, sources: np.ndarray, targets_mask: np.ndarray,
     """``engine="pallas"``: the jax engine's level recurrences through the
     fused mask+GEMM kernels (repro.kernels.mask_gemm) — compiled float32
     on TPU, float64 under the pallas interpreter elsewhere (the parity /
-    development path, same convention as repro.sim's pallas backends)."""
+    development path, same convention as repro.sim's pallas backends).
+    Which of the two ran is counted (``util.pallas[compiled]`` /
+    ``util.pallas[interpret]``)."""
     import jax
     import jax.numpy as jnp
 
+    from ..jaxenv import x64
     if jax.default_backend() == "tpu":
+        obs.counter("util.pallas[compiled]").add(1.0)
         return _loads_pallas_impl(g, sources, targets_mask, jax, jnp,
                                   demand, interpret=False, f64=False)
-    old_x64 = jax.config.jax_enable_x64
-    jax.config.update("jax_enable_x64", True)
-    try:
+    obs.counter("util.pallas[interpret]").add(1.0)
+    with x64():
         return _loads_pallas_impl(g, sources, targets_mask, jax, jnp,
                                   demand, interpret=True, f64=True)
-    finally:
-        jax.config.update("jax_enable_x64", old_x64)
 
 
 def _loads_pallas_impl(g: Graph, sources, targets_mask, jax, jnp,
@@ -954,13 +943,13 @@ def _loads_numpy(g: Graph, sources: np.ndarray, targets_mask: np.ndarray,
 
 def _exact_engine(g: Graph):
     """auto's exact-path choice by graph size: dense GEMMs while the dense
-    adjacency is reasonable, then jax (if present) up to util_jax_max, then
-    the memory-lean CSR sweep."""
+    adjacency is reasonable, then jax up to util_jax_max, then the
+    memory-lean CSR sweep."""
     fl = flags()
     if g.n <= fl.util_dense_max:
         obs.counter("util.engine[numpy]").add(1.0)
         return _loads_numpy
-    if _jax_available() and g.n <= fl.util_jax_max:
+    if g.n <= fl.util_jax_max:
         obs.counter("util.engine[jax]").add(1.0)
         return _loads_jax
     obs.counter("util.engine[csr]").add(1.0)
@@ -1012,14 +1001,8 @@ def arc_loads(g: Graph, sources=None, targets_mask: np.ndarray | None = None,
         elif eng == "csr":
             res = _loads_csr(g, sources, targets_mask)
         elif eng == "jax":
-            if not _jax_available():
-                raise RuntimeError(
-                    "engine='jax' requested but jax is not importable")
             res = _loads_jax(g, sources, targets_mask)
         elif eng == "pallas":
-            if not _jax_available():
-                raise RuntimeError(
-                    "engine='pallas' requested but jax is not importable")
             res = _loads_pallas(g, sources, targets_mask)
         else:  # auto, orbits disabled or explicit sources
             res = _exact_engine(g)(g, sources, targets_mask)
@@ -1117,14 +1100,8 @@ def arc_loads_weighted(g: Graph, demand,
         elif eng == "csr":
             res = _loads_csr(g, sources, targets_mask, demand)
         elif eng == "jax":
-            if not _jax_available():
-                raise RuntimeError(
-                    "engine='jax' requested but jax is not importable")
             res = _loads_jax(g, sources, targets_mask, demand)
         elif eng == "pallas":
-            if not _jax_available():
-                raise RuntimeError(
-                    "engine='pallas' requested but jax is not importable")
             res = _loads_pallas(g, sources, targets_mask, demand)
         else:  # auto / orbit: the exact-path choice by graph size
             res = _exact_engine(g)(g, sources, targets_mask, demand)

@@ -27,7 +27,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams as _CompilerParams
 
 __all__ = ["flash_attention"]
 
@@ -137,7 +136,7 @@ def _fwd(q, k, v, *, causal, window, q_offset, scale, block_q, block_k,
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)
@@ -257,7 +256,7 @@ def _bwd_impl(q, k, v, o, lse, do, *, causal, window, q_offset, scale,
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -280,7 +279,7 @@ def _bwd_impl(q, k, v, o, lse, do, *, causal, window, q_offset, scale,
                    jax.ShapeDtypeStruct((b, hq, skv, d), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -116,11 +116,6 @@ def _grid_call(kernel, lvl, mats, dists, out_shapes, b, n, block,
     as_ = pl.BlockSpec((bn, bn), lambda i, j, k, lvl: (k, j))
     ys = pl.BlockSpec((bb, bn), lambda i, j, k, lvl: (i, j))
 
-    kwargs = {}
-    if not interpret:
-        from ._compat import CompilerParams
-        kwargs["compiler_params"] = CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
     outs = pl.pallas_call(
         functools.partial(kernel, nk=grid[2]),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -132,7 +127,8 @@ def _grid_call(kernel, lvl, mats, dists, out_shapes, b, n, block,
         out_shape=[jax.ShapeDtypeStruct((rows, cols), dt)
                    for dt in out_shapes],
         interpret=interpret,
-        **kwargs,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(jnp.asarray([lvl], jnp.int32), x, adj, dist, *rest)
     return [o[:b, :n] for o in outs]
 

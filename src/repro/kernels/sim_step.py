@@ -29,8 +29,9 @@ numpy float64 engine remains the parity oracle and
 ``backend="pallas_interpret"`` runs this exact kernel through the
 pallas interpreter on CPU (tests/test_sim_kernel.py).
 
-Block structure and the compiler-params compat shim follow
-flash_attention.py / ssd_scan.py.
+Block structure follows flash_attention.py / ssd_scan.py.  The router
+block is chosen from K (:func:`router_block`) so the pipelined blocks fit
+the TPU's default scoped VMEM at every degree the simulator runs.
 """
 
 from __future__ import annotations
@@ -42,12 +43,32 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["fused_step_update", "fused_decision", "DEST_TILE"]
+__all__ = ["fused_step_update", "fused_decision", "router_block",
+           "DEST_TILE"]
 
 # dest-tile width: the TPU lane dimension; also the block the numpy
 # fused path (repro.sim.kernel) uses so both backends skip identical
 # (router, dest-tile) blocks
 DEST_TILE = 128
+
+# share of the default scoped VMEM (16 MiB on TPU v5e) the pipelined
+# blocks of one grid step may take; the rest holds the kernel's
+# temporaries (the update and the masked occupancy sum)
+_BLOCK_VMEM_BYTES = 12 * 2**20
+
+
+def router_block(k: int, itemsize: int = 4) -> int:
+    """Router rows per block: the largest power of two in [8, 128] whose
+    double-buffered ``(rows, K, DEST_TILE)`` blocks of the step kernel
+    (three inputs and one output) fit ``_BLOCK_VMEM_BYTES``.  K sits on
+    the sublane axis and pads to a multiple of 8.  Both kernels use it,
+    so their grids are identical."""
+    k_pad = -(-k // 8) * 8
+    rows = 128
+    while rows > 8 and 2 * 4 * rows * k_pad * DEST_TILE * itemsize \
+            > _BLOCK_VMEM_BYTES:
+        rows //= 2
+    return rows
 
 
 def _kernel(mask_ref, q_ref, split_ref, deliver_ref, fac_ref, corr_ref,
@@ -77,10 +98,8 @@ def _kernel(mask_ref, q_ref, split_ref, deliver_ref, fac_ref, corr_ref,
         qout_ref[...] = jnp.zeros_like(qout_ref)
 
 
-@functools.partial(jax.jit, static_argnames=("block_n", "block_d",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def fused_step_update(q, split, deliver, fac, corr, inflow, tile_mask,
-                      block_n: int = 128, block_d: int = DEST_TILE,
                       interpret: bool = False):
     """One VC's fused forward/throttle/enqueue update.
 
@@ -91,25 +110,20 @@ def fused_step_update(q, split, deliver, fac, corr, inflow, tile_mask,
       fac:       (N, K)    ``1 - share * damp`` retention factor.
       corr:      (N, K)    ``share * (1 - damp)`` delivery correction.
       inflow:    (N, M)    decided vc inflow to enqueue.
-      tile_mask: (ceil(M / block_d),) int32, nonzero = populated tile.
+      tile_mask: (ceil(M / DEST_TILE),) int32, nonzero = populated tile.
 
     Returns ``(q_out, o_out)``: the updated queues and the per-slot
     post-step occupancy ``q_out.sum(-1)``.
     """
     n, k, m = q.shape
-    bn = min(block_n, n)
-    bd = min(block_d, m)
+    bn = min(router_block(k, q.dtype.itemsize), n)
+    bd = min(DEST_TILE, m)
     grid = (pl.cdiv(n, bn), pl.cdiv(m, bd))
 
     qkd = pl.BlockSpec((bn, k, bd), lambda i, j, mask: (i, 0, j))
     nk = pl.BlockSpec((bn, k), lambda i, j, mask: (i, 0))
     nd = pl.BlockSpec((bn, bd), lambda i, j, mask: (i, j))
 
-    kwargs = {}
-    if not interpret:
-        from ._compat import CompilerParams
-        kwargs["compiler_params"] = CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"))
     return pl.pallas_call(
         functools.partial(_kernel, m=m),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -121,7 +135,8 @@ def fused_step_update(q, split, deliver, fac, corr, inflow, tile_mask,
         out_shape=[jax.ShapeDtypeStruct((n, k, m), q.dtype),
                    jax.ShapeDtypeStruct((n, k), q.dtype)],
         interpret=interpret,
-        **kwargs,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
     )(jnp.asarray(tile_mask, jnp.int32), q, split, deliver, fac, corr,
       inflow)
 
@@ -145,11 +160,9 @@ def _decision_kernel(mask_ref, b0_ref, split_ref, dist_ref, hval_ref,
         out_ref[...] = jnp.zeros_like(out_ref)
 
 
-@functools.partial(jax.jit, static_argnames=("thr", "block_n", "block_d",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("thr", "interpret"))
 def fused_decision(b0, split, dist, hval, cand, q_val, tile_mask,
-                   thr: float, block_n: int = 128,
-                   block_d: int = DEST_TILE, interpret: bool = False):
+                   thr: float, interpret: bool = False):
     """The per-hop UGAL decision as one blocked pass: divert candidates.
 
     Folds the ``q_min = einsum("nk,nkm->nm", b0, split)`` backlog gather
@@ -166,7 +179,7 @@ def fused_decision(b0, split, dist, hval, cand, q_val, tile_mask,
       hval:      (N, M)    mean two-leg detour estimate.
       cand:      (N, M)    enqueueing vc0 candidate fluid.
       q_val:     (N,)      weighted vc1 backlog.
-      tile_mask: (ceil(M / block_d),) int32, nonzero = candidates there.
+      tile_mask: (ceil(M / DEST_TILE),) int32, nonzero = candidates there.
       thr:       the threshold T in flit units (static: one compile per
                  SimConfig).
 
@@ -176,8 +189,8 @@ def fused_decision(b0, split, dist, hval, cand, q_val, tile_mask,
     discarded by the clipped write-back, exactly as in the step kernel.
     """
     n, k, m = split.shape
-    bn = min(block_n, n)
-    bd = min(block_d, m)
+    bn = min(router_block(k, split.dtype.itemsize), n)
+    bd = min(DEST_TILE, m)
     grid = (pl.cdiv(n, bn), pl.cdiv(m, bd))
 
     qkd = pl.BlockSpec((bn, k, bd), lambda i, j, mask: (i, 0, j))
@@ -185,11 +198,6 @@ def fused_decision(b0, split, dist, hval, cand, q_val, tile_mask,
     nd = pl.BlockSpec((bn, bd), lambda i, j, mask: (i, j))
     n1 = pl.BlockSpec((bn, 1), lambda i, j, mask: (i, 0))
 
-    kwargs = {}
-    if not interpret:
-        from ._compat import CompilerParams
-        kwargs["compiler_params"] = CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"))
     return pl.pallas_call(
         functools.partial(_decision_kernel, thr=thr),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -200,6 +208,7 @@ def fused_decision(b0, split, dist, hval, cand, q_val, tile_mask,
         ),
         out_shape=jax.ShapeDtypeStruct((n, m), cand.dtype),
         interpret=interpret,
-        **kwargs,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
     )(jnp.asarray(tile_mask, jnp.int32), b0, split, dist, hval, cand,
       q_val.reshape(n, 1))

@@ -23,7 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams as _CompilerParams
 
 __all__ = ["ssd_scan"]
 
@@ -102,7 +101,7 @@ def ssd_scan(x, dt, a_log, b_mat, c_mat, d_skip, *, chunk: int = 128,
         out_specs=pl.BlockSpec((1, chunk, 1, p), lambda ib, ih, ic: (ib, ic, ih, 0)),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
         scratch_shapes=[pltpu.VMEM((n, p), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, dt, a_log, b_mat, c_mat, d_skip)

@@ -27,11 +27,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-# jax >= 0.5 exposes shard_map at top level; older jax keeps it experimental
-_shard_map = getattr(jax, "shard_map", None)
-if _shard_map is None:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from ..configs.base import ArchConfig
 from .common import Boxed, box, truncated_normal_init
 from .layers import init_mlp, apply_mlp, rms_norm
@@ -248,14 +243,14 @@ def apply_moe(cfg: ArchConfig, p, x, *, mesh: Mesh | None = None,
                 out, aux = body(x3.reshape(bl * sl, m_), wi_, wg_, wu_, wd_)
                 return out.reshape(bl, sl, m_), aux
             tok3 = P(batch_axes or None, "model", None)
-            out3d, aux = _shard_map(
+            out3d, aux = jax.shard_map(
                 body3d, mesh=mesh, in_specs=(tok3, *weight_specs),
                 out_specs=(tok3, P()),
             )(h, p["router"], wg, wu, wd)
             out2d = None  # stay 3D end-to-end (no flatten round-trip)
         else:
             tok_spec = P(all_axes)  # tokens sharded over every axis
-            out2d, aux = _shard_map(
+            out2d, aux = jax.shard_map(
                 body, mesh=mesh,
                 in_specs=(tok_spec, *weight_specs),
                 out_specs=(tok_spec, P()),

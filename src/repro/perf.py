@@ -50,7 +50,7 @@ Topology-analytics flags (the batched all-source BFS/Brandes engine behind
                   engines instead of a full weighted sweep).
   util_dense_max=N — largest vertex count that uses dense (N, N)
                   adjacency GEMMs (default 6144); beyond it auto prefers
-                  jax (if importable, up to util_jax_max) then CSR.
+                  jax (up to util_jax_max) then CSR.
   util_jax_max=N — largest vertex count auto will hand to the jax dense
                   engine (default 12288).
   util_block=N  — source-block row count for the batched engines

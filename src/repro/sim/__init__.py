@@ -213,7 +213,7 @@ class Simulator:
         obs.gauge("sim.dest_cols.compacted").set(float(m_comp))
         obs.gauge("sim.compact_ratio").set(m_comp / max(m_dense, 1))
         # dense backends default to float64 (the jax step runs under a
-        # scoped enable_x64 — float32 rounding bias visibly shifts the
+        # scoped x64 — float32 rounding bias visibly shifts the
         # threshold rule's diversion duty cycle); the fused sparse-dest
         # backends default to float32, the TPU-native dtype, with the
         # dense float64 path as their parity oracle
@@ -223,8 +223,8 @@ class Simulator:
             self.tables = build_tables(g, self.active, dtype=self.dtype)
             self._step = self._make_step(self.tables)
         obs.counter(f"sim.backend[{self.backend}]").add(1.0)
-        # fault-state label -> (tables, compiled step); one compile per
-        # distinct fault state serves every run and every load probe
+        # fault-state label -> (tables, step); the jitted steps compile
+        # once per table shape, so fault states share one program
         self._fault_cache: dict = {}
 
     def _make_step(self, tb):
@@ -348,7 +348,7 @@ class Simulator:
 
         inj = (offered * inj_norm_run).astype(self.dtype)
         # host numpy in, host numpy out: the jax step converts on entry
-        # (under its enable_x64 scope, so float64 survives the round trip)
+        # (under its x64 scope, so float64 survives the round trip)
         st = init_state(t, self.dtype, dest_cols=cols).as_tuple()
         hist = np.empty((steps, 6), dtype=np.float64)
         # per-step surviving-demand total: each fault segment's history

@@ -37,8 +37,10 @@ Model (full semantics in docs/simulation.md):
 
 from __future__ import annotations
 
+import functools
 import re
 from dataclasses import dataclass, field
+from typing import NamedTuple
 
 import numpy as np
 
@@ -138,9 +140,9 @@ class SimState:
 
 
 def pick_backend(backend: str, work: int) -> str:
-    """Resolve ``auto`` (and validate explicit choices) against what is
-    importable: the fused sparse-dest backend beyond the dense cell cap,
-    JAX for large instances, numpy otherwise.  An ``auto`` request
+    """Resolve ``auto`` (and validate explicit choices) by size: the
+    fused sparse-dest backend beyond the dense cell cap, JAX for large
+    instances, numpy otherwise.  An ``auto`` request
     defers to the ``sim_backend`` perf flag first (REPRO_PERF), so whole
     runs can be pinned without threading a config through."""
     if backend == "auto":
@@ -155,13 +157,6 @@ def pick_backend(backend: str, work: int) -> str:
                          f"auto, numpy, jax, pallas, pallas_interpret")
     if backend == "auto" and work > SIM_MAX_CELLS:
         return "pallas"
-    try:
-        import jax  # noqa: F401
-    except ImportError:
-        if backend == "jax":
-            raise RuntimeError("sim backend 'jax' requested but jax is "
-                               "not importable; use backend='numpy'")
-        return "numpy"
     if backend == "jax":
         return "jax"
     return "jax" if work >= SIM_JAX_MIN_WORK else "numpy"
@@ -185,27 +180,95 @@ STAT_NAMES = ("delivered", "accepted", "offered", "occupancy",
               "src_backlog", "diverted")
 
 
+class _DenseSpec(NamedTuple):
+    """Everything static about one dense step: the tables' sizes and the
+    config scalars.  Two tables with equal specs share one compiled
+    ``jax`` step (fault states of one Simulator, for instance)."""
+
+    n: int
+    k: int
+    m: int
+    mode: str
+    thr: float
+    capacity: float
+    buffer: float
+    faulted: bool
+    dtype: str
+
+
+def _dense_tables(t: RouteTables, dtype) -> dict:
+    """The route tables one dense step reads, at the state dtype."""
+    asd = lambda a: np.asarray(a, dtype=dtype)
+    # mids available to a diverting router: m - 1 inside the active set
+    # (never via itself), all m mids from a transit-only (spine) router
+    in_active = np.zeros(t.n, dtype=bool)
+    in_active[t.active] = True
+    return {
+        "split": asd(t.split), "deliver": asd(t.deliver),
+        "spread": asd(t.spread),
+        # expected first-hop slot usage of freshly diverted fluid: the
+        # spread over intermediates pushed through the ECMP split (rows
+        # sum to 1)
+        "w_val": asd(np.einsum("nm,nkm->nk", t.spread, t.split)),
+        "dist_act": asd(t.dist_act), "hval_rem": asd(t.hval_rem),
+        "head_flat": t.head.reshape(-1), "active": t.active,
+        "n_mids": asd(t.m - in_active),
+        "spread_T": asd(t.spread.T),             # (M, N), mids x routers
+    }
+
+
 def make_step(t: RouteTables, cfg: SimConfig, backend: str, dtype):
     """Build ``step(state, inj, inj_cap) -> (state, stats)`` for one
     backend.  ``inj`` is the (N, M) per-step offered quantum, ``inj_cap``
     the (N,) per-source drain limit; both are traced arguments so one
-    compiled step serves a whole load sweep."""
+    compiled step serves a whole load sweep.  The ``jax`` step takes the
+    route tables as a device-resident argument, never as constants of
+    the compiled program."""
     from .. import obs
     obs.counter(f"sim.step_build[{backend}]").add(1.0)
-    if backend == "jax":
-        import jax.numpy as jnp
-        xp = jnp
+    spec = _DenseSpec(t.n, t.k, t.m, cfg.mode, cfg.threshold,
+                      float(cfg.capacity), float(min(cfg.buffer, _BIG)),
+                      bool(getattr(t, "faulted", False)),
+                      np.dtype(dtype).name)
+    tabs = _dense_tables(t, dtype)
+    if backend != "jax":
+        return functools.partial(_dense_step(np, spec), tabs)
+    return bind_tables(_jax_dense_step(spec), tabs, scoped_x64=True)
 
-        def scatter_rows(values, rows, nrows):
-            return jnp.zeros((nrows, values.shape[-1]), values.dtype) \
-                      .at[rows].add(values)
 
-        def zero_diag(a):
-            i = jnp.arange(a.shape[0])
-            return a.at[i, i].set(0.0)
-    else:
-        xp = np
+def bind_tables(jitted, tabs, scoped_x64: bool):
+    """Place ``tabs`` on the device once and return
+    ``step(state, inj, inj_cap) = jitted(tabs, state, inj, inj_cap)``.
+    With ``scoped_x64`` the placement and every call run under x64, so
+    float64 tables and state stay float64."""
+    import jax
 
+    from ..jaxenv import x64
+    if not scoped_x64:
+        tabs = jax.device_put(tabs)
+        return lambda state, inj, inj_cap: jitted(tabs, state, inj, inj_cap)
+    with x64():
+        tabs = jax.device_put(tabs)
+
+    def step(state, inj, inj_cap):
+        with x64():
+            return jitted(tabs, state, inj, inj_cap)
+
+    return step
+
+
+@functools.lru_cache(maxsize=16)
+def _jax_dense_step(spec: _DenseSpec):
+    import jax
+    import jax.numpy as jnp
+    return jax.jit(_dense_step(jnp, spec))
+
+
+def _dense_step(xp, spec: _DenseSpec):
+    """The dense step over ``xp`` (numpy or jax.numpy):
+    ``step(tabs, state, inj, inj_cap)`` with ``tabs`` from
+    :func:`_dense_tables`."""
+    if xp is np:
         def scatter_rows(values, rows, nrows):
             out = np.zeros((nrows, values.shape[-1]), values.dtype)
             np.add.at(out, rows, values)
@@ -215,38 +278,34 @@ def make_step(t: RouteTables, cfg: SimConfig, backend: str, dtype):
             a = a.copy()
             np.fill_diagonal(a, 0.0)
             return a
+    else:
+        def scatter_rows(values, rows, nrows):
+            return xp.zeros((nrows, values.shape[-1]), values.dtype) \
+                     .at[rows].add(values)
 
-    # constants stay host-side numpy; the jax trace captures them at the
-    # requested precision (the step runs under a scoped enable_x64, see
-    # below — float32 rounding bias measurably shifts the threshold rule's
-    # duty cycle, so both backends default to float64)
-    asd = lambda a: np.asarray(a, dtype=dtype)
-    n, k, m = t.n, t.k, t.m
-    split = asd(t.split)
-    deliver = asd(t.deliver)
-    spread = asd(t.spread)
-    # expected first-hop slot usage of freshly diverted fluid: the spread
-    # over intermediates pushed through the ECMP split (rows sum to 1)
-    w_val = asd(np.einsum("nm,nkm->nk", t.spread, t.split))
-    dist_act = asd(t.dist_act)
-    hval_rem = asd(t.hval_rem)
-    head_flat = xp.asarray(t.head.reshape(-1))
-    active = xp.asarray(t.active)
-    # mids available to a diverting router: m - 1 inside the active set
-    # (never via itself), all m mids from a transit-only (spine) router
-    in_active = np.zeros(t.n, dtype=bool)
-    in_active[t.active] = True
-    n_mids = asd(t.m - in_active)
+        def zero_diag(a):
+            i = xp.arange(a.shape[0])
+            return a.at[i, i].set(0.0)
+
+    # the jax step runs under a scoped x64 (see make_step) — float32
+    # rounding bias measurably shifts the threshold rule's duty cycle, so
+    # both backends default to float64
+    dtype = np.dtype(spec.dtype).type
+    n, k, m = spec.n, spec.k, spec.m
     # faulted tables break the uniform-spread structure the cheap pend
     # expansion below hard-codes; fall back to the general contraction
-    faulted = bool(getattr(t, "faulted", False))
-    spread_T = asd(t.spread.T)               # (M, N), mids x routers
-    mode, thr = cfg.mode, cfg.threshold
-    cap = dtype(cfg.capacity)
-    buf = dtype(min(cfg.buffer, _BIG))
-    midx = xp.arange(m)
+    faulted = spec.faulted
+    mode, thr = spec.mode, spec.thr
+    cap = dtype(spec.capacity)
+    buf = dtype(spec.buffer)
 
-    def step(state, inj, inj_cap):
+    def step(tabs, state, inj, inj_cap):
+        split, deliver = tabs["split"], tabs["deliver"]
+        spread, w_val = tabs["spread"], tabs["w_val"]
+        dist_act, hval_rem = tabs["dist_act"], tabs["hval_rem"]
+        head_flat, active = tabs["head_flat"], tabs["active"]
+        n_mids, spread_T = tabs["n_mids"], tabs["spread_T"]
+        midx = xp.arange(m)
         q0, q1, q2, src, pend, stage2 = state
 
         # -- start-of-step backlog: what the credit/decision logic sees --
@@ -379,13 +438,5 @@ def make_step(t: RouteTables, cfg: SimConfig, backend: str, dtype):
         stats = xp.stack([delivered, accepted, inj.sum(), occ,
                           src.sum(), div_eff.sum()])
         return (q0, q1, q2, src, pend, stage2), stats
-
-    if backend == "jax":
-        import jax
-        jitted = jax.jit(step)
-
-        def step(state, inj, inj_cap):  # noqa: F811 - jitted wrapper
-            with jax.experimental.enable_x64():
-                return jitted(state, inj, inj_cap)
 
     return step

@@ -60,12 +60,14 @@ instead of running as unfused dense ops.
 
 from __future__ import annotations
 
+import functools
 import time
 from collections import defaultdict
+from typing import NamedTuple
 
 import numpy as np
 
-from .engine import _BIG, _TINY, SimConfig
+from .engine import _BIG, _TINY, SimConfig, bind_tables
 from .tables import RouteTables
 
 __all__ = ["make_step_sparse", "step_aux", "resolve_dtype",
@@ -73,8 +75,8 @@ __all__ = ["make_step_sparse", "step_aux", "resolve_dtype",
 
 SPARSE_BACKENDS = ("pallas", "pallas_interpret")
 
-# dest-tile width shared with the pallas kernel (import kept lazy so the
-# numpy path works without jax installed)
+# dest-tile width shared with the pallas kernel (repro.kernels.sim_step,
+# imported lazily: the numpy path never loads jax)
 DEST_TILE = 128
 
 # live queue cells per step below which the slab loop stays serial:
@@ -247,14 +249,15 @@ def make_step_sparse(t: RouteTables, cfg: SimConfig, backend: str, dtype,
         # like the step implementations themselves
         obs.counter("sim.step_build[fused_decision]").add(1.0)
     if backend == "pallas":
-        try:
-            import jax
-            on_tpu = jax.default_backend() == "tpu"
-        except ImportError:
-            on_tpu = False
+        import jax
         # the pallas-vs-numpy dispatch, made observable: which fused
         # implementation actually ran is otherwise invisible to callers
-        if on_tpu:
+        if jax.default_backend() == "tpu":
+            if dtype == np.float64:
+                raise ValueError(
+                    "the pallas sim kernel runs float32 on TPU; float64 "
+                    "state needs backend='numpy' (the dense reference) "
+                    "or backend='pallas_interpret'")
             obs.counter("sim.step_build[pallas_tpu]").add(1.0)
             return _make_step_kernel(t, cfg, dtype, interpret=False,
                                      dest_cols=dest_cols)
@@ -634,61 +637,120 @@ def _make_step_fused_numpy(t: RouteTables, cfg: SimConfig, dtype,
 # ---------------------------------------------------------------------------
 
 
+class _KernelSpec(NamedTuple):
+    """Everything static about one kernel step: sizes, dest-axis tiling
+    and config scalars.  Tables with equal specs (and equal index-array
+    lengths) share one compiled step."""
+
+    n: int
+    k: int
+    widths: tuple          # per-VC dest-axis width (C, M, C)
+    compact: bool          # q0/q2 carry the compacted C axis
+    mode: str
+    thr: float
+    capacity: float
+    buffer: float
+    faulted: bool
+    dtype: str
+    interpret: bool
+
+
+def _kernel_tables(t: RouteTables, dtype, dest_cols=None) -> dict:
+    """Host-side arrays the kernel step reads: the (N, K, W) split and
+    deliver tables of each dest axis, the (N, W) decision tables, and
+    the arc-index structure (int32)."""
+    aux = step_aux(t)
+    n, k = t.n, t.k
+    nk = n * k
+    asd = lambda a: np.asarray(a, dtype=dtype)
+    idx = lambda a: np.asarray(a, dtype=np.int32)
+
+    def axis_tables(ax, sel):
+        return {"split": asd(t.split[:, :, sel]),
+                "deliver": asd(t.deliver[:, :, sel]),
+                "fix_arc": idx(ax.fix_arc), "fix_dst": idx(ax.fix_dst),
+                "fix_router": idx(ax.fix_router),
+                "dst_router": idx(ax.dst_router),
+                "dst_col": idx(ax.dst_col)}
+
+    sel = slice(None) if dest_cols is None else np.asarray(dest_cols,
+                                                           np.int64)
+    diag_mid, diag_col = _pool_diag(t, dest_cols)
+    in_active = np.zeros(n, dtype=bool)
+    in_active[t.active] = True
+    tabs = {
+        "F": axis_tables(_DestAxis(aux), slice(None)),
+        "dist_c": asd(t.dist_act[:, sel]), "hval_c": asd(t.hval_rem[:, sel]),
+        "spread": asd(t.spread),
+        "w_val": asd(np.einsum("nm,nkm->nk", t.spread, t.split)),
+        "spread_T": asd(t.spread.T), "n_mids": asd(t.m - in_active),
+        "active": idx(t.active), "head_flat": idx(t.head.reshape(-1)),
+        # reverse-arc gather: sentinel -> the appended zero row
+        "rev": idx(np.where(aux.rev >= 0, aux.rev, nk).reshape(n, k)),
+        "diag_mid": idx(diag_mid), "diag_col": idx(diag_col),
+    }
+    if dest_cols is not None:
+        tabs["C"] = axis_tables(_DestAxis(aux, dest_cols), sel)
+    return tabs
+
+
+def kernel_program(t: RouteTables, cfg: SimConfig, dtype, interpret,
+                   dest_cols=None):
+    """``(jitted, tabs)``: the compiled-once kernel step
+    ``jitted(tabs, state, inj, inj_cap)`` and the host-side route tables
+    it takes as its first argument."""
+    c = t.m if dest_cols is None else len(dest_cols)
+    spec = _KernelSpec(t.n, t.k, (c, t.m, c), dest_cols is not None,
+                       cfg.mode, cfg.threshold, float(cfg.capacity),
+                       float(min(cfg.buffer, _BIG)),
+                       bool(getattr(t, "faulted", False)),
+                       np.dtype(dtype).name, bool(interpret))
+    return _kernel_step(spec), _kernel_tables(t, dtype, dest_cols)
+
+
 def _make_step_kernel(t: RouteTables, cfg: SimConfig, dtype, interpret,
                       dest_cols=None):
+    jitted, tabs = kernel_program(t, cfg, dtype, interpret, dest_cols)
+    return bind_tables(jitted, tabs, scoped_x64=dtype == np.float64)
+
+
+@functools.lru_cache(maxsize=16)
+def _kernel_step(spec: _KernelSpec):
+    """The jitted ``step(tabs, state, inj, inj_cap)`` of one spec; the
+    route tables are an argument, so the program holds no table."""
     import jax
     import jax.numpy as jnp
 
     from ..kernels.sim_step import fused_decision, fused_step_update
 
-    aux = step_aux(t)
-    n, k, m = t.n, t.k, t.m
+    n, k = spec.n, spec.k
     nk = n * k
-    tile = aux.tile
-    axF = _DestAxis(aux)
-    axC = _DestAxis(aux, dest_cols) if dest_cols is not None else axF
-    ax = (axC, axF, axC)
-    widths = tuple(a.w for a in ax)
-    asd = lambda a: jnp.asarray(np.asarray(a, dtype=dtype))
-    split3F = asd(t.split)
-    deliverF = asd(t.deliver)
-    if dest_cols is not None:
-        csel = np.asarray(dest_cols, dtype=np.int64)
-        split3C = asd(t.split[:, :, csel])
-        deliverC = asd(t.deliver[:, :, csel])
-        dist_c = asd(t.dist_act[:, csel])
-        hval_c = asd(t.hval_rem[:, csel])
-    else:
-        split3C, deliverC = split3F, deliverF
-        dist_c = asd(t.dist_act)
-        hval_c = asd(t.hval_rem)
-    split3_v = (split3C, split3F, split3C)
-    deliver_v = (deliverC, deliverF, deliverC)
-    diag_mid, diag_col = _pool_diag(t, dest_cols)
-    spread = asd(t.spread)
-    w_val = asd(np.einsum("nm,nkm->nk", t.spread, t.split))
-    spread_T = asd(t.spread.T)
-    in_active = np.zeros(n, dtype=bool)
-    in_active[t.active] = True
-    n_mids = asd(t.m - in_active)
-    faulted = bool(getattr(t, "faulted", False))
-    active = jnp.asarray(t.active)
-    head_flat = jnp.asarray(t.head.reshape(-1))
-    # reverse-arc gather: sentinel -> the appended zero row
-    rev = jnp.asarray(np.where(aux.rev >= 0, aux.rev, nk).reshape(n, k))
-    mode, thr = cfg.mode, cfg.threshold
-    npdt = dtype
-    cap = npdt(cfg.capacity)
-    buf = npdt(min(cfg.buffer, _BIG))
-    thr = npdt(thr)
+    tile = DEST_TILE
+    widths = spec.widths
+    n_tiles = tuple(-(-w // tile) for w in widths)
+    interpret = spec.interpret
+    faulted = spec.faulted
+    mode = spec.mode
+    npdt = np.dtype(spec.dtype).type
+    cap = npdt(spec.capacity)
+    buf = npdt(spec.buffer)
+    thr = npdt(spec.thr)
     tiny = npdt(_TINY) if npdt == np.float64 else np.float32(1e-30)
 
     def tile_sums(x, v):                     # (..., W_v) -> (..., T_v)
-        pad = ax[v].n_tiles * tile - widths[v]
+        pad = n_tiles[v] * tile - widths[v]
         xp = jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
-        return xp.reshape(x.shape[:-1] + (ax[v].n_tiles, tile)).sum(-1)
+        return xp.reshape(x.shape[:-1] + (n_tiles[v], tile)).sum(-1)
 
-    def step_impl(state, inj, inj_cap):
+    def step_impl(tabs, state, inj, inj_cap):
+        axC = tabs["C"] if spec.compact else tabs["F"]
+        ax = (axC, tabs["F"], axC)           # per-VC dest-axis tables
+        dist_c, hval_c = tabs["dist_c"], tabs["hval_c"]
+        spread, w_val = tabs["spread"], tabs["w_val"]
+        spread_T, n_mids = tabs["spread_T"], tabs["n_mids"]
+        active, head_flat = tabs["active"], tabs["head_flat"]
+        rev = tabs["rev"]
+        diag_mid, diag_col = tabs["diag_mid"], tabs["diag_col"]
         q0, q1, q2, src, pend, stage2 = state
         qs = (q0, q1, q2)
         o = [q.reshape(nk, widths[v]).sum(axis=1)
@@ -698,16 +760,16 @@ def _make_step_kernel(t: RouteTables, cfg: SimConfig, dtype, interpret,
         arr, dl_sum, s_v, damp = [], [], [], []
         stage2_new = stage2
         for v, q in enumerate(qs):
-            axis = ax[v]
-            zrow = jnp.zeros((1, axis.w), dtype=q0.dtype)
-            mv = jnp.concatenate([q.reshape(nk, axis.w) * share[:, None],
+            axis, w = ax[v], widths[v]
+            zrow = jnp.zeros((1, w), dtype=q0.dtype)
+            mv = jnp.concatenate([q.reshape(nk, w) * share[:, None],
                                   zrow])
-            a = mv[rev.reshape(-1)].reshape(n, k, axis.w).sum(axis=1)
-            dl = a[axis.dst_router, axis.dst_col]
+            a = mv[rev.reshape(-1)].reshape(n, k, w).sum(axis=1)
+            dl = a[axis["dst_router"], axis["dst_col"]]
             if v == 1:
-                stage2_new = stage2_new.at[axis.dst_col].add(dl)
+                stage2_new = stage2_new.at[axis["dst_col"]].add(dl)
             dl_sum.append(dl.sum())
-            a = a.at[axis.dst_router, axis.dst_col].set(0.0)
+            a = a.at[axis["dst_router"], axis["dst_col"]].set(0.0)
             own = (o[v] * (1.0 - share)).reshape(n, k).sum(axis=1)
             space = jnp.maximum(buf - own, 0.0)
             desire = a.sum(axis=1)
@@ -725,10 +787,10 @@ def _make_step_kernel(t: RouteTables, cfg: SimConfig, dtype, interpret,
             # retention of o minus the delivered fluid's extra share
             axis = ax[v]
             f = (o[v] * (1.0 - share * damp[v])).reshape(n, k).sum(axis=1)
-            vals = qs[v].reshape(nk, axis.w)[axis.fix_arc, axis.fix_dst]
-            fx = vals * share[axis.fix_arc] \
-                * (1.0 - damp[v][axis.fix_arc])
-            return f - jnp.zeros(n, q0.dtype).at[axis.fix_router].add(fx)
+            arc = axis["fix_arc"]
+            vals = qs[v].reshape(nk, widths[v])[arc, axis["fix_dst"]]
+            fx = vals * share[arc] * (1.0 - damp[v][arc])
+            return f - jnp.zeros(n, q0.dtype).at[axis["fix_router"]].add(fx)
 
         # -- conversions ----------------------------------------------
         occ2_now = rowfwd(2) + arr[2].sum(axis=1)
@@ -763,7 +825,7 @@ def _make_step_kernel(t: RouteTables, cfg: SimConfig, dtype, interpret,
                 q_val = (b1 * w_val).sum(axis=1)
                 ctm = tile_sums(cand.sum(axis=0), 0)
                 div_cand = fused_decision(
-                    b0, split3_v[0], dist_c, hval_c, cand, q_val,
+                    b0, ax[0]["split"], dist_c, hval_c, cand, q_val,
                     (ctm > 0).astype(jnp.int32), thr=float(thr),
                     interpret=interpret)
             occ1_now = rowfwd(1) + arr[1].sum(axis=1)
@@ -801,7 +863,8 @@ def _make_step_kernel(t: RouteTables, cfg: SimConfig, dtype, interpret,
             mass = tile_sums(qs[v].reshape(nk, widths[v]).sum(axis=0)
                              + inflow[v].sum(axis=0), v)
             tmask = (mass > 0).astype(jnp.int32)
-            qn, on = fused_step_update(qs[v], split3_v[v], deliver_v[v],
+            qn, on = fused_step_update(qs[v], ax[v]["split"],
+                                       ax[v]["deliver"],
                                        fac2, corr2, inflow[v], tmask,
                                        interpret=interpret)
             occ = occ + on.sum()
@@ -812,10 +875,4 @@ def _make_step_kernel(t: RouteTables, cfg: SimConfig, dtype, interpret,
                            src.sum(), div_eff.sum()])
         return (new_qs[0], new_qs[1], new_qs[2], src, pend, stage2), stats
 
-    jitted = jax.jit(step_impl)
-    if dtype == np.float64:
-        def step(state, inj, inj_cap):
-            with jax.experimental.enable_x64():
-                return jitted(state, inj, inj_cap)
-        return step
-    return jitted
+    return jax.jit(step_impl)

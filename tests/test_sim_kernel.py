@@ -296,6 +296,65 @@ def test_dense_backend_above_cap_names_the_escape_hatch():
 
 
 # ---------------------------------------------------------------------------
+# the compiled step programs: tables are arguments, not constants
+# ---------------------------------------------------------------------------
+
+
+def test_kernel_step_program_holds_no_tables():
+    """The lowered pn16 kernel step takes the route tables as an
+    argument: its text stays far below the ~86 MB it had when the
+    (546, 17, 546) tables were inlined as literals."""
+    from repro.sim.engine import init_state
+    from repro.sim.kernel import kernel_program
+    from repro.sim.tables import build_tables
+    g = pn_graph(16)
+    t = build_tables(g, np.arange(g.n), dtype=np.float32)
+    jitted, tabs = kernel_program(t, SimConfig(routing="ugal_threshold(0)"),
+                                  np.float32, interpret=True)
+    state = init_state(t, np.float32).as_tuple()
+    text = jitted.lower(tabs, state, np.zeros((t.n, t.m), np.float32),
+                        np.zeros(t.n, np.float32)).as_text()
+    assert len(text) < 1_000_000
+
+
+@pytest.mark.parametrize("backend", ["jax", "pallas_interpret"])
+def test_fault_states_share_one_compile(backend):
+    """Two fault states of one Simulator have tables of the same shapes,
+    so the second one compiles nothing."""
+    import jax
+    dem = _uniform(G16)
+    sim = Simulator(G16, SimConfig(routing="ugal_threshold(0)",
+                                   backend=backend), demand=dem)
+    sim.run(dem, 0.5, 4, events=[(2, random_faults(G16, k_links=2,
+                                                   seed=0))])
+    compiles = []
+
+    def listen(event, duration, **kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(duration)
+
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        r = sim.run(dem, 0.5, 4, events=[(2, random_faults(G16, k_links=2,
+                                                           seed=1))])
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+    assert r.faults is not None
+    assert compiles == []
+
+
+def test_pallas_float64_on_tpu_raises(monkeypatch):
+    """On a TPU the pallas kernel runs float32; float64 is refused by
+    name instead of being compiled (or quietly interpreted)."""
+    import jax
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = SimConfig(routing="ugal_threshold(0)", backend="pallas",
+                    dtype="float64")
+    with pytest.raises(ValueError, match="float32 on TPU"):
+        Simulator(G16, cfg, demand=_uniform(G16))
+
+
+# ---------------------------------------------------------------------------
 # utilization: the mask+GEMM kernel engine
 # ---------------------------------------------------------------------------
 

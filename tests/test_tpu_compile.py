@@ -1,0 +1,123 @@
+"""Ahead-of-time compiles of the simulator's device path for a TPU v5e.
+
+Nothing here runs on a chip: the TPU compiler, which is installed with
+JAX, compiles for a described ``v5e:2x2`` topology and refuses what the
+chip would refuse — a kernel whose blocks overflow the scoped VMEM, a
+tiling the chip cannot lay out, a program larger than device memory.
+The shapes are the simulator's own: pn16 ``(546, 17, 546)``, PN(27)
+with its dest axis compacted to the 757 points ``(1514, 28, 757)`` and
+whole ``(1514, 28, 1514)``, and the paper's Table-5 PN(31)
+``(1986, 32, 1986)``.
+
+The topology is described inside a module fixture, never at import: only
+one process may hold the TPU library, and every test worker imports this
+file.
+"""
+
+from __future__ import annotations
+
+import os
+
+import numpy as np
+import pytest
+
+HBM_BYTES = 16 * 10**9          # one TPU v5e chip
+
+STEP_SHAPES = [(546, 17, 546), (1514, 28, 757), (1514, 28, 1514),
+               (1986, 32, 1986)]
+SHAPE_IDS = ["x".join(map(str, s)) for s in STEP_SHAPES]
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    import jax
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler: nothing to compile against
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a TPU compile written to the persistent cache cannot be read back
+    # without a chip; keep these compiles out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+def _spec(one_chip, shape, dtype=np.float32):
+    import jax
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+
+@pytest.mark.parametrize("shape", STEP_SHAPES, ids=SHAPE_IDS)
+def test_fused_step_update_compiles(one_chip, shape):
+    from repro.kernels.sim_step import DEST_TILE, fused_step_update
+    n, k, m = shape
+    s = lambda *sh: _spec(one_chip, sh)
+    args = (s(n, k, m), s(n, k, m), s(n, k, m), s(n, k), s(n, k), s(n, m),
+            _spec(one_chip, (-(-m // DEST_TILE),), np.int32))
+    compiled = fused_step_update.lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("shape", STEP_SHAPES, ids=SHAPE_IDS)
+def test_fused_decision_compiles(one_chip, shape):
+    from repro.kernels.sim_step import DEST_TILE, fused_decision
+    n, k, m = shape
+    s = lambda *sh: _spec(one_chip, sh)
+    args = (s(n, k), s(n, k, m), s(n, m), s(n, m), s(n, m), s(n),
+            _spec(one_chip, (-(-m // DEST_TILE),), np.int32))
+    compiled = fused_decision.lower(*args, thr=0.0).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("step", ["frontier", "backward"])
+def test_mask_gemm_compiles(one_chip, step):
+    from repro.kernels.mask_gemm import backward_step, frontier_step
+    b, n = 512, 1986                 # a source block of PN(31)
+    f = lambda: _spec(one_chip, (b, n))
+    adj = _spec(one_chip, (n, n))
+    dist = _spec(one_chip, (b, n), np.int32)
+    if step == "frontier":
+        lowered = frontier_step.lower(f(), adj, dist, f(), 3)
+    else:
+        lowered = backward_step.lower(f(), adj, dist, f(), f(), 2)
+    assert "tpu_custom_call" in lowered.compile().as_text()
+
+
+@pytest.mark.parametrize("q", [16, 27], ids=["pn16", "pn27_compacted"])
+def test_kernel_step_compiles_within_hbm(one_chip, q):
+    """The whole jitted kernel step, route tables as arguments, fits one
+    chip's memory.  PN(27) runs as in kernel_bench.pn27_ugal: every
+    router active (ugal), the dest axis compacted to the 757 points."""
+    import jax
+
+    from repro.core import pn_graph
+    from repro.sim import SimConfig
+    from repro.sim.engine import init_state
+    from repro.sim.kernel import kernel_program
+    from repro.sim.tables import build_tables
+
+    g = pn_graph(q)
+    t = build_tables(g, np.arange(g.n), dtype=np.float32)
+    cols = None if q == 16 else np.arange(q * q + q + 1)
+    cfg = SimConfig(routing="ugal_threshold(0)")
+    jitted, tabs = kernel_program(t, cfg, np.float32, interpret=False,
+                                  dest_cols=cols)
+    state = init_state(t, np.float32, dest_cols=cols).as_tuple()
+    c = t.m if cols is None else len(cols)
+    inj, cap = np.zeros((t.n, c), np.float32), np.zeros(t.n, np.float32)
+    shapes = jax.tree.map(lambda a: _spec(one_chip, a.shape, a.dtype),
+                          (tabs, state, inj, cap))
+    compiled = jitted.lower(*shapes).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes)
+    assert 0 < total < HBM_BYTES, total
