@@ -5,11 +5,14 @@ Three faces (see docs/observability.md for the full taxonomy):
 
 * **tracing** — ``with obs.span("sim.sweep", pattern=...):`` records
   nestable wall-time spans into the active session, exported as
-  Chrome-trace/Perfetto JSON (``Session.write_chrome``) or JSONL
-  (``write_jsonl``).  The hot seams are pre-instrumented: utilization
-  engine dispatch, routing solves (incl. ``blend_optimum`` probe
-  counts), ``saturation_sweep`` bracket/bisection probes, placement
-  ``greedy_swap``, fault surgery, and the sim backend dispatch.
+  Chrome-trace/Perfetto JSON (``Session.write_chrome``); where JAX is
+  loaded each span is also a ``jax.profiler.TraceAnnotation``, so a
+  captured JAX profile shows it beside the device ops.  The hot seams
+  are pre-instrumented: utilization engine dispatch, routing solves
+  (incl. ``blend_optimum`` probe counts), ``saturation_sweep``
+  bracket/bisection probes, placement ``greedy_swap``, fault surgery,
+  the sim backend dispatch, and the Simulator's table build and host
+  step loop.
 * **metrics** — ``obs.counter("sim.delivered").add(x)`` etc. against the
   session's :class:`MetricsRegistry`; the simulator publishes its
   conservation counters (bit-exact with ``SimRun``'s own accounting)

@@ -1,7 +1,7 @@
 """Span tracing for repro.obs: nestable context managers recording wall
 time into an active :class:`Session`, exported as Chrome-trace/Perfetto
-JSON (``chrome://tracing`` / https://ui.perfetto.dev) or a compact JSONL
-event log.
+JSON (``chrome://tracing`` / https://ui.perfetto.dev) and, where JAX
+is loaded, into any JAX profile being captured.
 
 Two span flavors share one class:
 
@@ -16,6 +16,12 @@ Two span flavors share one class:
   span that launched it — the trainer/serve step-timing fix rides on
   this.
 
+A recording span also enters ``jax.profiler.TraceAnnotation(name)``
+while it is open, so a captured JAX profile holds the spans on its host
+plane, on the device events' clock.  It does so only when ``"jax" in
+sys.modules``: repro.obs never imports JAX itself, and the no-op
+singleton and an unrecorded ``timed`` span never touch it.
+
 Timestamps are ``perf_counter_ns`` relative to the session start;
 ``Session.chrome_trace()`` converts to the microsecond ``ts``/``dur``
 complete events ("ph": "X") Perfetto renders with nesting inferred per
@@ -25,6 +31,7 @@ thread.
 from __future__ import annotations
 
 import json
+import sys
 import threading
 import time
 
@@ -36,10 +43,12 @@ __all__ = ["Span", "Session", "NULL_SPAN", "NULL_SESSION"]
 class Span:
     """One timed region.  Use as a context manager; ``set(**attrs)``
     annotates mid-flight, ``sync(*objs)`` defers the end timestamp past
-    ``jax.block_until_ready`` of the registered objects."""
+    ``jax.block_until_ready`` of the registered objects.  A span that
+    records into a session is also a JAX profiler annotation while it is
+    open (where JAX is loaded)."""
 
     __slots__ = ("name", "attrs", "_session", "_t0_ns", "dur_ns",
-                 "_sync_objs", "_depth")
+                 "_sync_objs", "_depth", "_annotation")
 
     def __init__(self, name: str, attrs: dict, session: "Session | None"):
         self.name = name
@@ -49,6 +58,7 @@ class Span:
         self.dur_ns = 0
         self._sync_objs = None
         self._depth = 0
+        self._annotation = None
 
     def set(self, **attrs) -> "Span":
         self.attrs.update(attrs)
@@ -70,6 +80,10 @@ class Span:
             tls = s._tls
             self._depth = getattr(tls, "depth", 0)
             tls.depth = self._depth + 1
+            jax = sys.modules.get("jax")
+            if jax is not None:
+                self._annotation = jax.profiler.TraceAnnotation(self.name)
+                self._annotation.__enter__()
         self._t0_ns = time.perf_counter_ns()
         return self
 
@@ -80,7 +94,13 @@ class Span:
                 jax.block_until_ready(self._sync_objs)
             except Exception:
                 pass  # jax absent or non-pytree objects: nothing to wait on
+            # a span outlives its block (``with ... as sp``): holding what
+            # it waited on would keep those device buffers alive
+            self._sync_objs = None
         self.dur_ns = time.perf_counter_ns() - self._t0_ns
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+            self._annotation = None
         s = self._session
         if s is not None:
             s._tls.depth = self._depth
@@ -162,7 +182,6 @@ class Session:
         self.stream = stream
         self.events: list = []  # (name, t0_ns, dur_ns, tid, depth, attrs)
         self._t0_ns = time.perf_counter_ns()
-        self._wall0 = time.time()
         self._lock = threading.Lock()
         self._tls = threading.local()
 
@@ -238,23 +257,6 @@ class Session:
     def write_chrome(self, path: str) -> None:
         with open(path, "w") as fh:
             json.dump(self.chrome_trace(), fh)
-
-    def write_jsonl(self, path: str) -> None:
-        """Compact one-event-per-line log; the first line is a header
-        with the schema tag and the session's unix start time."""
-        with self._lock:
-            events = list(self.events)
-        with open(path, "w") as fh:
-            fh.write(json.dumps({"schema": "repro.obs/1",
-                                 "t0_unix": self._wall0,
-                                 "mode": self.mode}) + "\n")
-            for name, t0, dur, tid, depth, attrs in events:
-                rec = {"name": name, "ts_us": round(t0 / 1e3, 3),
-                       "dur_us": round(dur / 1e3, 3), "tid": tid,
-                       "depth": depth}
-                if attrs:
-                    rec["attrs"] = _json_safe(attrs)
-                fh.write(json.dumps(rec) + "\n")
 
 
 def _json_safe(attrs: dict) -> dict:

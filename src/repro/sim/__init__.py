@@ -48,8 +48,9 @@ from .. import obs
 from ..core.graph import Graph
 from ..core.traffic import make_pattern, normalize_demand, saturation_report
 from ..obs import balance_stats
-from .engine import (SIM_JAX_MIN_WORK, SIM_MAX_CELLS, SimConfig, SimState,
-                     init_state, make_step, parse_sim_routing, pick_backend)
+from .engine import (SIM_JAX_MIN_WORK, SIM_MAX_CELLS, BoundStep, SimConfig,
+                     SimState, init_state, make_step, parse_sim_routing,
+                     pick_backend)
 from .faults import FaultEvent, apply_fault_surgery, normalize_events
 from .kernel import SPARSE_BACKENDS, make_step_sparse, resolve_dtype
 from .tables import RouteTables, build_tables
@@ -220,8 +221,13 @@ class Simulator:
         self.dtype = resolve_dtype(config.dtype, self.backend)
         with obs.span("sim.build_tables", backend=self.backend, n=g.n,
                       dests=len(self.active)):
-            self.tables = build_tables(g, self.active, dtype=self.dtype)
+            with obs.span("sim.route_tables"):
+                self.tables = build_tables(g, self.active, dtype=self.dtype)
             self._step = self._make_step(self.tables)
+        # the jitted steps read their state on the device: host states
+        # are placed explicitly (sim.state_put), never inside a step call
+        self._put = (self._step.put if isinstance(self._step, BoundStep)
+                     else None)
         obs.counter(f"sim.backend[{self.backend}]").add(1.0)
         # fault-state label -> (tables, step); the jitted steps compile
         # once per table shape, so fault states share one program
@@ -241,10 +247,31 @@ class Simulator:
         key = fs.label
         if key not in self._fault_cache:
             with obs.span("sim.fault_tables", label=key):
-                tb = build_tables(self.g, self.active, dtype=self.dtype,
-                                  faults=fs)
+                with obs.span("sim.route_tables"):
+                    tb = build_tables(self.g, self.active, dtype=self.dtype,
+                                      faults=fs)
                 self._fault_cache[key] = (tb, self._make_step(tb))
         return self._fault_cache[key]
+
+    def _place(self, st, sp):
+        """Host state ``st`` on the jitted step's device, counted in
+        ``sim.state_put_bytes`` and synced on span ``sp``; the numpy
+        steps read it where it is."""
+        if self._put is None:
+            return st
+        st = self._put(st)
+        sp.sync(st)
+        obs.counter("sim.state_put_bytes").add(
+            float(sum(a.nbytes for a in st)))
+        return st
+
+    def _fetch(self, st):
+        """State ``st`` as host arrays; what comes off the jitted step's
+        device is counted in ``sim.state_fetch_bytes``."""
+        if self._put is not None:
+            obs.counter("sim.state_fetch_bytes").add(
+                float(sum(a.nbytes for a in st)))
+        return tuple(np.asarray(a) for a in st)
 
     def default_steps(self, events=None) -> int:
         """Enough steps for the slowest feedback loop to settle: several
@@ -303,53 +330,62 @@ class Simulator:
 
     def _run(self, demand, offered, steps, window, events,
              per_dest=False) -> SimRun:
-        t = self.tables
-        demand = np.asarray(demand, dtype=np.float64)
-        if demand.shape != (t.n, t.n):
-            raise ValueError(f"demand is {demand.shape}, graph has N={t.n}")
-        inj_norm = demand[:, t.active]
-        lost = demand.sum() - inj_norm.sum()
-        if lost > 1e-9 * max(demand.sum(), 1.0):
-            raise ValueError("demand addresses routers outside the active "
-                             "set; pass a matching targets_mask")
-        if np.abs(np.diagonal(demand)).sum() > 1e-9 * max(demand.sum(), 1.0):
-            raise ValueError("demand has self-addressed (diagonal) entries; "
-                             "zero the diagonal (TrafficPattern.demand and "
-                             "placement_demand already do)")
-        if inj_norm.sum() <= 0:
-            raise ValueError("demand matrix is all zero")
-        cols = self.dest_cols
-        if cols is not None:
-            off_cols = inj_norm.sum(axis=0)
-            outside = float(off_cols.sum() - off_cols[cols].sum())
-            if outside > 1e-9 * max(float(off_cols.sum()), 1.0):
-                raise ValueError(
-                    "demand addresses destination columns outside the "
-                    "compacted dest axis this Simulator was built for; "
-                    "rebuild with Simulator(demand=...) covering them, "
-                    "or SimConfig(compact='off')")
-            inj_norm_run = inj_norm[:, cols]
-        else:
-            inj_norm_run = inj_norm
-        evs = normalize_events(events)
-        steps = (self.default_steps(events=evs) if steps is None
-                 else int(steps))
-        window = max(steps // 3, 8) if window is None else int(window)
-        window = min(window, steps)
+        with obs.span("sim.run_inputs"):
+            t = self.tables
+            demand = np.asarray(demand, dtype=np.float64)
+            if demand.shape != (t.n, t.n):
+                raise ValueError(f"demand is {demand.shape}, graph has "
+                                 f"N={t.n}")
+            inj_norm = demand[:, t.active]
+            lost = demand.sum() - inj_norm.sum()
+            if lost > 1e-9 * max(demand.sum(), 1.0):
+                raise ValueError("demand addresses routers outside the "
+                                 "active set; pass a matching targets_mask")
+            if (np.abs(np.diagonal(demand)).sum()
+                    > 1e-9 * max(demand.sum(), 1.0)):
+                raise ValueError("demand has self-addressed (diagonal) "
+                                 "entries; zero the diagonal "
+                                 "(TrafficPattern.demand and "
+                                 "placement_demand already do)")
+            if inj_norm.sum() <= 0:
+                raise ValueError("demand matrix is all zero")
+            cols = self.dest_cols
+            if cols is not None:
+                off_cols = inj_norm.sum(axis=0)
+                outside = float(off_cols.sum() - off_cols[cols].sum())
+                if outside > 1e-9 * max(float(off_cols.sum()), 1.0):
+                    raise ValueError(
+                        "demand addresses destination columns outside the "
+                        "compacted dest axis this Simulator was built for; "
+                        "rebuild with Simulator(demand=...) covering them, "
+                        "or SimConfig(compact='off')")
+                inj_norm_run = inj_norm[:, cols]
+            else:
+                inj_norm_run = inj_norm
+            evs = normalize_events(events)
+            steps = (self.default_steps(events=evs) if steps is None
+                     else int(steps))
+            window = max(steps // 3, 8) if window is None else int(window)
+            window = min(window, steps)
 
-        if evs and evs[-1].step >= steps:
-            raise ValueError(f"fault event at step {evs[-1].step} is past "
-                             f"the run's {steps} steps")
-        # segments of constant fault state: (start, end, FaultSet | None)
-        marks = ([] if evs and evs[0].step == 0 else [(0, None)])
-        marks += [(e.step, e.faults) for e in evs]
-        segs = [(s0, (marks[i + 1][0] if i + 1 < len(marks) else steps), fs)
-                for i, (s0, fs) in enumerate(marks)]
+            if evs and evs[-1].step >= steps:
+                raise ValueError(f"fault event at step {evs[-1].step} is "
+                                 f"past the run's {steps} steps")
+            # segments of constant fault state: (start, end, FaultSet | None)
+            marks = ([] if evs and evs[0].step == 0 else [(0, None)])
+            marks += [(e.step, e.faults) for e in evs]
+            segs = [(s0, (marks[i + 1][0] if i + 1 < len(marks)
+                          else steps), fs)
+                    for i, (s0, fs) in enumerate(marks)]
 
-        inj = (offered * inj_norm_run).astype(self.dtype)
-        # host numpy in, host numpy out: the jax step converts on entry
-        # (under its x64 scope, so float64 survives the round trip)
-        st = init_state(t, self.dtype, dest_cols=cols).as_tuple()
+            inj = (offered * inj_norm_run).astype(self.dtype)
+        # the state lives where the step reads it (the device, for the
+        # jitted steps) from the first step to each segment's end; a
+        # step-0 fault's surgery runs on the host zeros before placement
+        with obs.span("sim.state_put") as sp:
+            st = init_state(t, self.dtype, dest_cols=cols).as_tuple()
+            if segs[0][2] is None:
+                st = self._place(st, sp)
         hist = np.empty((steps, 6), dtype=np.float64)
         # per-step surviving-demand total: each fault segment's history
         # is normalized by ITS OWN fault state's surviving demand, not
@@ -391,12 +427,17 @@ class Simulator:
         for s0, s1, fs in segs:
             tb, step_fn = self._tables_for(fs)
             if fs is not None:
+                # on the host: the zero state, or the previous segment's
+                # fetched at its end
                 with obs.span("sim.fault_surgery", label=fs.label,
                               step=s0):
                     st, dropped = apply_fault_surgery(st, tb,
                                                       dest_cols=cols)
                 dropped_total += dropped
                 obs.counter("sim.fault_events").add(1.0)
+                if self._put is not None:
+                    with obs.span("sim.state_put") as sp:
+                        st = self._place(st, sp)
             rt = tb.routable if cols is None else tb.routable[:, cols]
             inj_seg = (inj * rt).astype(self.dtype) if tb.faulted else inj
             inj_cap = (self.config.inj_factor
@@ -415,8 +456,10 @@ class Simulator:
                     if mon.stab_win else None,
                     dropped_total)
             for i in range(s0, s1):
-                st, stats = step_fn(st, inj_seg, inj_cap)
-                hist[i] = np.asarray(stats, dtype=np.float64)
+                with obs.span("sim.step_dispatch"):
+                    st, stats = step_fn(st, inj_seg, inj_cap)
+                with obs.span("sim.stats_pull"):
+                    hist[i] = np.asarray(stats, dtype=np.float64)
                 if cap is not None:
                     cap.on_step(i, st, hist[i])
                 if mon is not None:
@@ -429,96 +472,101 @@ class Simulator:
                     else:
                         pd_off = pd_off + off_dest
                     pd_last = dm
-            if fs is not None:
-                st = tuple(np.asarray(a) for a in st)
-        # final fluid state, host-side (tests probe buffer occupancies)
-        self.last_state = SimState(*(np.asarray(a) for a in st))
+            if s1 < steps:
+                with obs.span("sim.state_fetch"):
+                    st = self._fetch(st)
+        # final fluid state, host-side (tests probe buffer occupancies);
+        # the previous run's is dropped in the same span
+        with obs.span("sim.state_fetch"):
+            self.last_state = SimState(*self._fetch(st))
 
-        # theta in the FINAL fault state's surviving demand units — the
-        # value the analytic degraded_report theta is comparable to
-        total = float(seg_total[-1])
-        if total <= 0:
-            raise ValueError("faults removed every offered demand")
-        # a mid-run segment can have zero surviving demand (recovered
-        # later); its normalized history rows are identically zero
-        norm = np.where(seg_total > 0, seg_total, np.inf)
-        w = hist[-window:]
-        delivered_rate = float(w[:, 0].mean())
-        accepted_rate = float(w[:, 1].mean())
-        occupancy = float(w[:, 3].mean())
-        src_backlog = float(hist[-1, 4])
-        injected_cum = float(hist[:, 2].sum())
-        delivered_cum = float(hist[:, 0].sum())
-        residual = abs(injected_cum - delivered_cum - float(hist[-1, 3])
-                       - src_backlog - dropped_total) \
-            / max(injected_cum, 1e-30)
-        acc_cum = float(hist[:, 1].sum())
-        div_cum = float(hist[:, 5].sum())
-        alpha = 1.0 - div_cum / max(acc_cum, 1e-30)
-        latency = occupancy / max(delivered_rate, 1e-30)
-        dest_stab_min = dest_stab_mean = float("nan")
-        if per_dest and pd_last is not None and pd_off is not None:
-            sel = pd_off > 0
-            if sel.any():
-                delivered_d = pd_mass0 - pd_last + pd_off
-                stab = np.clip(delivered_d[sel] / pd_off[sel], 0.0, None)
-                dest_stab_min = float(stab.min())
-                dest_stab_mean = float(stab.mean())
-        final_fs = segs[-1][2]
-        if sess is not None and sess.enabled:
-            # publish the run's own accounting: the SAME float values the
-            # residual/alpha identities above consumed, so the counters
-            # are bit-exact with the returned SimRun (pinned in
-            # tests/test_obs.py, mid-run fault surgery included)
-            m = sess.metrics
-            m.counter("sim.runs").add(1.0)
-            m.counter("sim.steps").add(float(steps))
-            m.counter("sim.injected").add(injected_cum)
-            m.counter("sim.delivered").add(delivered_cum)
-            m.counter("sim.accepted").add(acc_cum)
-            m.counter("sim.diverted").add(div_cum)
-            m.counter("sim.dropped").add(dropped_total)
-            m.gauge("sim.final_occupancy").set(float(hist[-1, 3]))
-            m.gauge("sim.final_src_backlog").set(src_backlog)
-            m.gauge("sim.residual").set(residual)
-            m.gauge("sim.alpha").set(alpha)
-            m.gauge("sim.delivered_rate").set(delivered_rate)
-            m.gauge("sim.theta").set(delivered_rate / total)
-            if cap is not None:
-                cap.finalize()
-            else:
-                # cheap one-shot balance proxy: the FINAL state's per-arc
-                # occupancy clipped at capacity (below saturation every
-                # queue drains each step, so this IS the per-link flit
-                # rate); the window-averaged sim.link_util histogram
-                # needs per-step series capture
-                ls = self.last_state
-                o_tot = (np.asarray(ls.q0, np.float64).sum(-1)
-                         + np.asarray(ls.q1, np.float64).sum(-1)
-                         + np.asarray(ls.q2, np.float64).sum(-1))
-                capacity = float(self.config.capacity)
-                util = (np.minimum(o_tot[np.asarray(tb.slot_ok, bool)],
-                                   capacity) / capacity)
-                m.histogram("sim.link_util_final").observe_many(util)
-                _publish_balance(m, util)
-        return SimRun(
-            routing=self.config.routing, offered=float(offered),
-            theta=delivered_rate / total, delivered_rate=delivered_rate,
-            accepted_rate=accepted_rate, latency=latency, alpha=alpha,
-            occupancy=occupancy, src_backlog=src_backlog, residual=residual,
-            steps=steps, window=window, backend=self.backend,
-            dropped=dropped_total,
-            faults=(None if final_fs is None or final_fs.empty
-                    else final_fs.label),
-            dest_stability_min=dest_stab_min,
-            dest_stability_mean=dest_stab_mean,
-            history={"delivered": hist[:, 0] / norm,
-                     "accepted": hist[:, 1] / norm,
-                     "offered": hist[:, 2] / norm,
-                     "occupancy": hist[:, 3], "src_backlog": hist[:, 4],
-                     "diverted": hist[:, 5],
-                     "fault_events": np.array([e.step for e in evs],
-                                              dtype=np.int64)})
+        with obs.span("sim.run_result"):
+            # theta in the FINAL fault state's surviving demand units — the
+            # value the analytic degraded_report theta is comparable to
+            total = float(seg_total[-1])
+            if total <= 0:
+                raise ValueError("faults removed every offered demand")
+            # a mid-run segment can have zero surviving demand (recovered
+            # later); its normalized history rows are identically zero
+            norm = np.where(seg_total > 0, seg_total, np.inf)
+            w = hist[-window:]
+            delivered_rate = float(w[:, 0].mean())
+            accepted_rate = float(w[:, 1].mean())
+            occupancy = float(w[:, 3].mean())
+            src_backlog = float(hist[-1, 4])
+            injected_cum = float(hist[:, 2].sum())
+            delivered_cum = float(hist[:, 0].sum())
+            residual = abs(injected_cum - delivered_cum - float(hist[-1, 3])
+                           - src_backlog - dropped_total) \
+                / max(injected_cum, 1e-30)
+            acc_cum = float(hist[:, 1].sum())
+            div_cum = float(hist[:, 5].sum())
+            alpha = 1.0 - div_cum / max(acc_cum, 1e-30)
+            latency = occupancy / max(delivered_rate, 1e-30)
+            dest_stab_min = dest_stab_mean = float("nan")
+            if per_dest and pd_last is not None and pd_off is not None:
+                sel = pd_off > 0
+                if sel.any():
+                    delivered_d = pd_mass0 - pd_last + pd_off
+                    stab = np.clip(delivered_d[sel] / pd_off[sel], 0.0, None)
+                    dest_stab_min = float(stab.min())
+                    dest_stab_mean = float(stab.mean())
+            final_fs = segs[-1][2]
+            if sess is not None and sess.enabled:
+                # publish the run's own accounting: the SAME float values the
+                # residual/alpha identities above consumed, so the counters
+                # are bit-exact with the returned SimRun (pinned in
+                # tests/test_obs.py, mid-run fault surgery included)
+                m = sess.metrics
+                m.counter("sim.runs").add(1.0)
+                m.counter("sim.steps").add(float(steps))
+                m.counter("sim.injected").add(injected_cum)
+                m.counter("sim.delivered").add(delivered_cum)
+                m.counter("sim.accepted").add(acc_cum)
+                m.counter("sim.diverted").add(div_cum)
+                m.counter("sim.dropped").add(dropped_total)
+                m.gauge("sim.final_occupancy").set(float(hist[-1, 3]))
+                m.gauge("sim.final_src_backlog").set(src_backlog)
+                m.gauge("sim.residual").set(residual)
+                m.gauge("sim.alpha").set(alpha)
+                m.gauge("sim.delivered_rate").set(delivered_rate)
+                m.gauge("sim.theta").set(delivered_rate / total)
+                if cap is not None:
+                    cap.finalize()
+                else:
+                    # cheap one-shot balance proxy: the FINAL state's per-arc
+                    # occupancy clipped at capacity (below saturation every
+                    # queue drains each step, so this IS the per-link flit
+                    # rate); the window-averaged sim.link_util histogram
+                    # needs per-step series capture
+                    ls = self.last_state
+                    o_tot = (np.asarray(ls.q0, np.float64).sum(-1)
+                             + np.asarray(ls.q1, np.float64).sum(-1)
+                             + np.asarray(ls.q2, np.float64).sum(-1))
+                    capacity = float(self.config.capacity)
+                    util = (np.minimum(o_tot[np.asarray(tb.slot_ok, bool)],
+                                       capacity) / capacity)
+                    m.histogram("sim.link_util_final").observe_many(util)
+                    _publish_balance(m, util)
+            return SimRun(
+                routing=self.config.routing, offered=float(offered),
+                theta=delivered_rate / total, delivered_rate=delivered_rate,
+                accepted_rate=accepted_rate, latency=latency, alpha=alpha,
+                occupancy=occupancy, src_backlog=src_backlog,
+                residual=residual,
+                steps=steps, window=window, backend=self.backend,
+                dropped=dropped_total,
+                faults=(None if final_fs is None or final_fs.empty
+                        else final_fs.label),
+                dest_stability_min=dest_stab_min,
+                dest_stability_mean=dest_stab_mean,
+                history={"delivered": hist[:, 0] / norm,
+                         "accepted": hist[:, 1] / norm,
+                         "offered": hist[:, 2] / norm,
+                         "occupancy": hist[:, 3], "src_backlog": hist[:, 4],
+                         "diverted": hist[:, 5],
+                         "fault_events": np.array([e.step for e in evs],
+                                                  dtype=np.int64)})
 
 
 def _dest_mass_host(st):

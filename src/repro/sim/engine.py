@@ -37,6 +37,7 @@ Model (full semantics in docs/simulation.md):
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import re
 from dataclasses import dataclass, field
@@ -46,7 +47,7 @@ import numpy as np
 
 from .tables import RouteTables
 
-__all__ = ["SimConfig", "SimState", "make_step", "init_state",
+__all__ = ["SimConfig", "SimState", "BoundStep", "make_step", "init_state",
            "parse_sim_routing", "pick_backend", "SIM_JAX_MIN_WORK",
            "SIM_MAX_CELLS"]
 
@@ -230,31 +231,43 @@ def make_step(t: RouteTables, cfg: SimConfig, backend: str, dtype):
                       float(cfg.capacity), float(min(cfg.buffer, _BIG)),
                       bool(getattr(t, "faulted", False)),
                       np.dtype(dtype).name)
-    tabs = _dense_tables(t, dtype)
+    with obs.span("sim.step_tables"):
+        tabs = _dense_tables(t, dtype)
     if backend != "jax":
         return functools.partial(_dense_step(np, spec), tabs)
-    return bind_tables(_jax_dense_step(spec), tabs, scoped_x64=True)
+    return BoundStep(_jax_dense_step(spec), tabs, scoped_x64=True)
 
 
-def bind_tables(jitted, tabs, scoped_x64: bool):
-    """Place ``tabs`` on the device once and return
+class BoundStep:
+    """A jitted step with its route tables on the device:
     ``step(state, inj, inj_cap) = jitted(tabs, state, inj, inj_cap)``.
-    With ``scoped_x64`` the placement and every call run under x64, so
-    float64 tables and state stay float64."""
-    import jax
+    The tables are placed once, at construction (span ``sim.table_put``,
+    counter ``sim.table_put_bytes``); :meth:`put` places a host state the
+    same way.  With ``scoped_x64`` the placements and every call run
+    under x64, so float64 tables and state stay float64."""
 
-    from ..jaxenv import x64
-    if not scoped_x64:
-        tabs = jax.device_put(tabs)
-        return lambda state, inj, inj_cap: jitted(tabs, state, inj, inj_cap)
-    with x64():
-        tabs = jax.device_put(tabs)
+    def __init__(self, jitted, tabs, scoped_x64: bool):
+        import jax
 
-    def step(state, inj, inj_cap):
-        with x64():
-            return jitted(tabs, state, inj, inj_cap)
+        from .. import obs
+        from ..jaxenv import x64
+        self.jitted = jitted
+        self._scope = x64 if scoped_x64 else contextlib.nullcontext
+        with self._scope(), obs.span("sim.table_put") as sp:
+            self.tabs = jax.device_put(tabs)
+            sp.sync(self.tabs)
+        obs.counter("sim.table_put_bytes").add(
+            float(sum(a.nbytes for a in jax.tree.leaves(self.tabs))))
 
-    return step
+    def put(self, state):
+        """``state`` (a pytree of host arrays) on the step's device."""
+        import jax
+        with self._scope():
+            return jax.device_put(state)
+
+    def __call__(self, state, inj, inj_cap):
+        with self._scope():
+            return self.jitted(self.tabs, state, inj, inj_cap)
 
 
 @functools.lru_cache(maxsize=16)
@@ -278,7 +291,15 @@ def _dense_step(xp, spec: _DenseSpec):
             a = a.copy()
             np.fill_diagonal(a, 0.0)
             return a
+
+        def scope(name):
+            return contextlib.nullcontext()
     else:
+        import jax
+        # metadata only: the device ops keep their names, and their
+        # ``tf_op`` in a profile carries the scope
+        scope = jax.named_scope
+
         def scatter_rows(values, rows, nrows):
             return xp.zeros((nrows, values.shape[-1]), values.dtype) \
                      .at[rows].add(values)
@@ -326,9 +347,13 @@ def _dense_step(xp, spec: _DenseSpec):
         cont2 = mv2 - del2
 
         # -- credits: continuing arrivals need space at the head ---------
-        arr0 = scatter_rows(cont0.reshape(n * k, m), head_flat, n + 1)[:n]
-        arr1 = scatter_rows(cont1.reshape(n * k, m), head_flat, n + 1)[:n]
-        arr2 = scatter_rows(cont2.reshape(n * k, m), head_flat, n + 1)[:n]
+        with scope("forward_gather"):
+            arr0 = scatter_rows(cont0.reshape(n * k, m), head_flat,
+                                n + 1)[:n]
+            arr1 = scatter_rows(cont1.reshape(n * k, m), head_flat,
+                                n + 1)[:n]
+            arr2 = scatter_rows(cont2.reshape(n * k, m), head_flat,
+                                n + 1)[:n]
 
         def throttle(q, mv, arr):
             own = q.sum(axis=(1, 2)) - mv.sum(axis=(1, 2))
@@ -353,64 +378,67 @@ def _dense_step(xp, spec: _DenseSpec):
         delivered = del0.sum() + del2.sum()
 
         # -- phase-1 conversions: intermediate reached, draw final dests -
-        stage2 = stage2 + del1.sum(axis=(0, 1))       # (M,) by intermediate
-        occ2_now = q2.sum(axis=(1, 2)) + arr2.sum(-1)
-        avail2 = xp.maximum(buf - occ2_now, 0.0)[active]
-        pend_sum = pend.sum(-1)
-        drain = xp.minimum(xp.minimum(stage2, avail2), pend_sum)
-        mix = pend / xp.maximum(pend_sum, _TINY)[:, None]
-        take = drain[:, None] * mix                   # (M, M) mid x dest
-        pend = pend - take
-        stage2 = stage2 - drain
-        # a conversion whose intermediate IS the destination is delivered
-        delivered = delivered + take[midx, midx].sum()
-        take = zero_diag(take)
-        conv2 = scatter_rows(take, active, n)         # (N, M) vc2 inflow
+        with scope("conversions"):
+            stage2 = stage2 + del1.sum(axis=(0, 1))   # (M,) by intermediate
+            occ2_now = q2.sum(axis=(1, 2)) + arr2.sum(-1)
+            avail2 = xp.maximum(buf - occ2_now, 0.0)[active]
+            pend_sum = pend.sum(-1)
+            drain = xp.minimum(xp.minimum(stage2, avail2), pend_sum)
+            mix = pend / xp.maximum(pend_sum, _TINY)[:, None]
+            take = drain[:, None] * mix                   # (M, M) mid x dest
+            pend = pend - take
+            stage2 = stage2 - drain
+            # a conversion whose intermediate IS the destination is delivered
+            delivered = delivered + take[midx, midx].sum()
+            take = zero_diag(take)
+            conv2 = scatter_rows(take, active, n)         # (N, M) vc2 inflow
 
         # -- injection: drain the backlog up to the per-step cap ---------
-        src = src + inj
-        srcsum = src.sum(-1)
-        frac = xp.minimum(srcsum, inj_cap) / xp.maximum(srcsum, _TINY)
-        q_inj = src * frac[:, None]
-        src = src - q_inj
+        with scope("injection"):
+            src = src + inj
+            srcsum = src.sum(-1)
+            frac = xp.minimum(srcsum, inj_cap) / xp.maximum(srcsum, _TINY)
+            q_inj = src * frac[:, None]
+            src = src - q_inj
 
         # -- routing decision on every vc0 enqueue (per-hop UGAL) --------
-        cand = arr0 + q_inj                           # (N, M) vc0 stream
-        if mode == "minimal":
-            div_eff = xp.zeros_like(cand)
-            s1d = xp.ones_like(s0)
-        else:
-            if mode == "valiant":
-                div_ind = xp.ones_like(cand)
+        with scope("decision"):
+            cand = arr0 + q_inj                           # (N, M) vc0 stream
+            if mode == "minimal":
+                div_eff = xp.zeros_like(cand)
+                s1d = xp.ones_like(s0)
             else:
-                # backlog = occupancy beyond what one step drains (a queue
-                # holding exactly its in-flight fluid is uncongested),
-                # averaged over the slots the fluid would actually join:
-                # minimal fluid splits per the ECMP table, diverted fluid
-                # per the expected first hop toward a uniform intermediate
-                b0 = xp.maximum(o0 - cap, 0.0)
-                b1 = xp.maximum(o1 - cap, 0.0)
-                q_min = xp.einsum("nk,nkm->nm", b0, split)
-                q_val = (b1 * w_val).sum(axis=1)
-                div_ind = (dist_act * q_min
-                           > thr + hval_rem * q_val[:, None]).astype(dtype)
-            div_cand = cand * div_ind
-            occ1_now = q1.sum(axis=(1, 2)) + arr1.sum(-1)
-            space1 = xp.maximum(buf - occ1_now, 0.0)
-            desire1 = div_cand.sum(-1)
-            s1d = xp.minimum(1.0, space1 / xp.maximum(desire1, _TINY))
-            div_eff = div_cand * s1d[:, None]         # blocked stays vc0
-            # commit (mid, dest) pairs with the SAME per-row spread the
-            # vc1 fluid routes by: (r, d) fluid puts spread[r, m] on mid
-            # m, i.e. pend += spread.T @ div_eff, expanded to O(N * M)
-            # via spread[r, m] = (1 - [active[m] == r]) / n_mids[r];
-            # faulted spreads are not uniform, so take the O(N * M^2)
-            # contraction literally there
-            if faulted:
-                pend = pend + spread_T @ div_eff
-            else:
-                scaled = div_eff / n_mids[:, None]
-                pend = pend + scaled.sum(0)[None, :] - scaled[active, :]
+                if mode == "valiant":
+                    div_ind = xp.ones_like(cand)
+                else:
+                    # backlog = occupancy beyond what one step drains (a queue
+                    # holding exactly its in-flight fluid is uncongested),
+                    # averaged over the slots the fluid would actually join:
+                    # minimal fluid splits per the ECMP table, diverted fluid
+                    # per the expected first hop toward a uniform intermediate
+                    b0 = xp.maximum(o0 - cap, 0.0)
+                    b1 = xp.maximum(o1 - cap, 0.0)
+                    q_min = xp.einsum("nk,nkm->nm", b0, split)
+                    q_val = (b1 * w_val).sum(axis=1)
+                    div_ind = (dist_act * q_min
+                               > thr + hval_rem * q_val[:, None]).astype(dtype)
+                div_cand = cand * div_ind
+                occ1_now = q1.sum(axis=(1, 2)) + arr1.sum(-1)
+                space1 = xp.maximum(buf - occ1_now, 0.0)
+                desire1 = div_cand.sum(-1)
+                s1d = xp.minimum(1.0, space1 / xp.maximum(desire1, _TINY))
+                div_eff = div_cand * s1d[:, None]         # blocked stays vc0
+                # commit (mid, dest) pairs with the SAME per-row spread the
+                # vc1 fluid routes by: (r, d) fluid puts spread[r, m] on mid
+                # m, i.e. pend += spread.T @ div_eff, expanded to O(N * M)
+                # via spread[r, m] = (1 - [active[m] == r]) / n_mids[r];
+                # faulted spreads are not uniform, so take the O(N * M^2)
+                # contraction literally there
+                if faulted:
+                    pend = pend + spread_T @ div_eff
+                else:
+                    scaled = div_eff / n_mids[:, None]
+                    pend = pend + scaled.sum(0)[None, :] - scaled[active, :]
 
         keep = cand - div_eff
         keep_frac = keep / xp.maximum(cand, _TINY)

@@ -67,7 +67,7 @@ from typing import NamedTuple
 
 import numpy as np
 
-from .engine import _BIG, _TINY, SimConfig, bind_tables
+from .engine import _BIG, _TINY, BoundStep, SimConfig
 from .tables import RouteTables
 
 __all__ = ["make_step_sparse", "step_aux", "resolve_dtype",
@@ -699,19 +699,22 @@ def kernel_program(t: RouteTables, cfg: SimConfig, dtype, interpret,
     """``(jitted, tabs)``: the compiled-once kernel step
     ``jitted(tabs, state, inj, inj_cap)`` and the host-side route tables
     it takes as its first argument."""
+    from .. import obs
     c = t.m if dest_cols is None else len(dest_cols)
     spec = _KernelSpec(t.n, t.k, (c, t.m, c), dest_cols is not None,
                        cfg.mode, cfg.threshold, float(cfg.capacity),
                        float(min(cfg.buffer, _BIG)),
                        bool(getattr(t, "faulted", False)),
                        np.dtype(dtype).name, bool(interpret))
-    return _kernel_step(spec), _kernel_tables(t, dtype, dest_cols)
+    with obs.span("sim.step_tables"):
+        tabs = _kernel_tables(t, dtype, dest_cols)
+    return _kernel_step(spec), tabs
 
 
 def _make_step_kernel(t: RouteTables, cfg: SimConfig, dtype, interpret,
                       dest_cols=None):
     jitted, tabs = kernel_program(t, cfg, dtype, interpret, dest_cols)
-    return bind_tables(jitted, tabs, scoped_x64=dtype == np.float64)
+    return BoundStep(jitted, tabs, scoped_x64=dtype == np.float64)
 
 
 @functools.lru_cache(maxsize=16)
@@ -762,9 +765,10 @@ def _kernel_step(spec: _KernelSpec):
         for v, q in enumerate(qs):
             axis, w = ax[v], widths[v]
             zrow = jnp.zeros((1, w), dtype=q0.dtype)
-            mv = jnp.concatenate([q.reshape(nk, w) * share[:, None],
-                                  zrow])
-            a = mv[rev.reshape(-1)].reshape(n, k, w).sum(axis=1)
+            with jax.named_scope("forward_gather"):
+                mv = jnp.concatenate([q.reshape(nk, w) * share[:, None],
+                                      zrow])
+                a = mv[rev.reshape(-1)].reshape(n, k, w).sum(axis=1)
             dl = a[axis["dst_router"], axis["dst_col"]]
             if v == 1:
                 stage2_new = stage2_new.at[axis["dst_col"]].add(dl)
@@ -793,51 +797,54 @@ def _kernel_step(spec: _KernelSpec):
             return f - jnp.zeros(n, q0.dtype).at[axis["fix_router"]].add(fx)
 
         # -- conversions ----------------------------------------------
-        occ2_now = rowfwd(2) + arr[2].sum(axis=1)
-        avail2 = jnp.maximum(buf - occ2_now, 0.0)[active]
-        pend_sum = pend.sum(axis=1)
-        drain = jnp.minimum(jnp.minimum(stage2, avail2), pend_sum)
-        mix = pend / jnp.maximum(pend_sum, tiny)[:, None]
-        take = drain[:, None] * mix                # (M, C)
-        pend = pend - take
-        stage2 = stage2 - drain
-        delivered = delivered + take[diag_mid, diag_col].sum()
-        take = take.at[diag_mid, diag_col].set(0.0)
-        conv2 = jnp.zeros((n, widths[2]), q0.dtype).at[active].set(take)
+        with jax.named_scope("conversions"):
+            occ2_now = rowfwd(2) + arr[2].sum(axis=1)
+            avail2 = jnp.maximum(buf - occ2_now, 0.0)[active]
+            pend_sum = pend.sum(axis=1)
+            drain = jnp.minimum(jnp.minimum(stage2, avail2), pend_sum)
+            mix = pend / jnp.maximum(pend_sum, tiny)[:, None]
+            take = drain[:, None] * mix                # (M, C)
+            pend = pend - take
+            stage2 = stage2 - drain
+            delivered = delivered + take[diag_mid, diag_col].sum()
+            take = take.at[diag_mid, diag_col].set(0.0)
+            conv2 = jnp.zeros((n, widths[2]), q0.dtype).at[active].set(take)
 
         # -- injection -------------------------------------------------
-        src = src + inj
-        srcsum = src.sum(axis=1)
-        frac = jnp.minimum(srcsum, inj_cap) / jnp.maximum(srcsum, tiny)
-        q_inj = src * frac[:, None]
-        src = src - q_inj
+        with jax.named_scope("injection"):
+            src = src + inj
+            srcsum = src.sum(axis=1)
+            frac = jnp.minimum(srcsum, inj_cap) / jnp.maximum(srcsum, tiny)
+            q_inj = src * frac[:, None]
+            src = src - q_inj
 
         # -- decision (fused kernel: q_min + threshold + mask) ---------
-        cand = arr[0] + q_inj
-        if mode == "minimal":
-            div_eff = jnp.zeros_like(cand)
-        else:
-            if mode == "valiant":
-                div_cand = cand
+        with jax.named_scope("decision"):
+            cand = arr[0] + q_inj
+            if mode == "minimal":
+                div_eff = jnp.zeros_like(cand)
             else:
-                b0 = jnp.maximum(o[0] - cap, 0.0).reshape(n, k)
-                b1 = jnp.maximum(o[1] - cap, 0.0).reshape(n, k)
-                q_val = (b1 * w_val).sum(axis=1)
-                ctm = tile_sums(cand.sum(axis=0), 0)
-                div_cand = fused_decision(
-                    b0, ax[0]["split"], dist_c, hval_c, cand, q_val,
-                    (ctm > 0).astype(jnp.int32), thr=float(thr),
-                    interpret=interpret)
-            occ1_now = rowfwd(1) + arr[1].sum(axis=1)
-            space1 = jnp.maximum(buf - occ1_now, 0.0)
-            desire1 = div_cand.sum(axis=1)
-            s1d = jnp.minimum(1.0, space1 / jnp.maximum(desire1, tiny))
-            div_eff = div_cand * s1d[:, None]
-            if faulted:
-                pend = pend + spread_T @ div_eff
-            else:
-                scaled = div_eff / n_mids[:, None]
-                pend = pend + scaled.sum(0)[None, :] - scaled[active, :]
+                if mode == "valiant":
+                    div_cand = cand
+                else:
+                    b0 = jnp.maximum(o[0] - cap, 0.0).reshape(n, k)
+                    b1 = jnp.maximum(o[1] - cap, 0.0).reshape(n, k)
+                    q_val = (b1 * w_val).sum(axis=1)
+                    ctm = tile_sums(cand.sum(axis=0), 0)
+                    div_cand = fused_decision(
+                        b0, ax[0]["split"], dist_c, hval_c, cand, q_val,
+                        (ctm > 0).astype(jnp.int32), thr=float(thr),
+                        interpret=interpret)
+                occ1_now = rowfwd(1) + arr[1].sum(axis=1)
+                space1 = jnp.maximum(buf - occ1_now, 0.0)
+                desire1 = div_cand.sum(axis=1)
+                s1d = jnp.minimum(1.0, space1 / jnp.maximum(desire1, tiny))
+                div_eff = div_cand * s1d[:, None]
+                if faulted:
+                    pend = pend + spread_T @ div_eff
+                else:
+                    scaled = div_eff / n_mids[:, None]
+                    pend = pend + scaled.sum(0)[None, :] - scaled[active, :]
 
         keep = cand - div_eff
         keep_frac = keep / jnp.maximum(cand, tiny)

@@ -66,11 +66,23 @@ def test_span_nesting_and_chrome_trace(tmp_path):
     for e in xs:                                     # Perfetto essentials
         assert {"name", "cat", "ts", "dur", "pid", "tid"} <= set(e)
 
-    jl = tmp_path / "trace.jsonl"
-    sess.write_jsonl(str(jl))
-    lines = [json.loads(ln) for ln in jl.read_text().splitlines()]
-    assert lines[0]["schema"] == "repro.obs/1"
-    assert len(lines) == 4
+
+def test_span_releases_what_it_synced():
+    """A span kept past its block (``with ... as sp``) does not keep the
+    arrays it waited on alive: a Simulator run's placed zero state would
+    otherwise stay on the device for the whole run."""
+    import gc
+    import weakref
+
+    import jax.numpy as jnp
+    with obs.session(mode="trace"):
+        x = jnp.zeros(4)
+        ref = weakref.ref(x)
+        with obs.span("placed") as sp:
+            sp.sync(x)
+        del x
+        gc.collect()
+        assert ref() is None and sp.seconds > 0
 
 
 def test_timed_measures_with_obs_off():
@@ -211,6 +223,128 @@ def test_sim_series_capture_under_trace():
     assert snap["metrics"]["sim.dest_stability.min"]["value"] > 0.9
     names = [e[0] for e in sess.events]
     assert "sim.run" in names and "sim.build_tables" in names
+
+
+# -- the Simulator's host spans, byte counters and profiler annotations ----
+
+G16 = torus3d_graph(4, 4, 1)
+JAX_UGAL = SimConfig(routing="ugal_threshold(0)", backend="jax")
+
+
+def _assert_nested(sess, child, parent, count):
+    """``count`` spans ``child``, each one level inside ``parent``'s one
+    span, on its thread and within its interval."""
+    (_, p0, pdur, ptid, pdepth, _), = [e for e in sess.events
+                                       if e[0] == parent]
+    kids = [e for e in sess.events if e[0] == child]
+    assert len(kids) == count, child
+    for _, t0, dur, tid, depth, _ in kids:
+        assert tid == ptid and depth == pdepth + 1
+        assert p0 <= t0 and t0 + dur <= p0 + pdur
+
+
+def test_sim_spans_per_build_and_run():
+    with obs.session(mode="trace") as sess:
+        sim = Simulator(G16, JAX_UGAL)
+        sim.run(_uniform(G16), 0.3, steps=7)
+    for child in ("sim.route_tables", "sim.step_tables", "sim.table_put"):
+        _assert_nested(sess, child, "sim.build_tables", 1)
+    for child in ("sim.run_inputs", "sim.state_put", "sim.state_fetch",
+                  "sim.run_result"):
+        _assert_nested(sess, child, "sim.run", 1)
+    _assert_nested(sess, "sim.step_dispatch", "sim.run", 7)
+    _assert_nested(sess, "sim.stats_pull", "sim.run", 7)
+
+
+@pytest.mark.parametrize("backend,enabled", [("jax", True), ("jax", False),
+                                             ("pallas_interpret", False)])
+def test_sim_byte_counters_match_moved_arrays(backend, enabled):
+    """The byte counters are the bytes of the state ``init_state`` builds
+    (sent and fetched once per run) and of the tables placed once per
+    build, also in a session with ``enabled = False``."""
+    import jax
+
+    from repro.sim.engine import init_state
+    dem = _uniform(G16)
+    with obs.session(mode="trace", series=False) as sess:
+        sess.enabled = enabled
+        sim = Simulator(G16, SimConfig(routing="ugal_threshold(0)",
+                                       backend=backend), demand=dem)
+        sim.run(dem, 0.3, steps=3)
+        sim.run(dem, 0.4, steps=3)
+    state = sum(a.nbytes for a in init_state(
+        sim.tables, sim.dtype, dest_cols=sim.dest_cols).as_tuple())
+    tables = sum(a.nbytes for a in jax.tree.leaves(sim._step.tabs))
+    m = sess.metrics
+    assert m.counter("sim.table_put_bytes").value == tables > 0
+    assert m.counter("sim.state_put_bytes").value == 2 * state > 0
+    assert m.counter("sim.state_fetch_bytes").value == 2 * state
+
+
+def test_sim_numpy_backend_moves_nothing():
+    with obs.session(mode="trace") as sess:
+        sim = Simulator(G16, SimConfig(backend="numpy"))
+        sim.run(_uniform(G16), 0.3, steps=3)
+    assert not any(k.endswith("_bytes") for k in sess.metrics.names())
+    assert "sim.table_put" not in sess.span_summary()
+    _assert_nested(sess, "sim.state_put", "sim.run", 1)
+
+
+@pytest.mark.parametrize("event_step", [None, 0, 5])
+def test_sim_histories_bitwise_with_session_and_placement(event_step):
+    """A run's histories are the same bits with no session, under a
+    trace session, and with the state handed to the first step as host
+    arrays instead of placed on the device first."""
+    dem = _uniform(G16)
+    events = (None if event_step is None else
+              [(event_step, random_faults(G16, k_links=2, seed=0))])
+    sim = Simulator(G16, JAX_UGAL)
+    runs = [sim.run(dem, 0.5, steps=12, events=events)]
+    with obs.session(mode="trace"):
+        runs.append(sim.run(dem, 0.5, steps=12, events=events))
+    sim._put = None
+    runs.append(sim.run(dem, 0.5, steps=12, events=events))
+    for r in runs[1:]:
+        for key, h in runs[0].history.items():
+            np.testing.assert_array_equal(r.history[key], h, err_msg=key)
+
+
+def test_sim_run_without_session_never_syncs(monkeypatch):
+    import jax
+    calls = []
+    real = jax.block_until_ready
+
+    def counted(x):
+        calls.append(1)
+        return real(x)
+
+    monkeypatch.setattr(jax, "block_until_ready", counted)
+    assert obs.current() is None
+    assert obs.span("sim.state_put") is obs.NULL_SPAN
+    Simulator(G16, JAX_UGAL).run(_uniform(G16), 0.3, steps=4)
+    assert calls == []
+    with obs.session(mode="trace"):
+        Simulator(G16, JAX_UGAL).run(_uniform(G16), 0.3, steps=4)
+    assert len(calls) == 2  # sim.table_put and sim.state_put
+
+
+def test_sim_spans_land_in_a_jax_profile(tmp_path):
+    import jax
+    dem = _uniform(G16)
+    sim = Simulator(G16, JAX_UGAL)
+    sim.run(dem, 0.3, steps=2)  # compiles outside the capture
+    with obs.session(mode="trace"):
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            sim.run(dem, 0.3, steps=3)
+        finally:
+            jax.profiler.stop_trace()
+    path = next(tmp_path.rglob("*.xplane.pb"))
+    pd = jax.profiler.ProfileData.from_file(str(path))
+    names = [ev.name for plane in pd.planes if plane.name == "/host:CPU"
+             for line in plane.lines for ev in line.events]
+    assert names.count("sim.run") == 1
+    assert names.count("sim.stats_pull") == 3
 
 
 # -- the obs=none fast path ------------------------------------------------

@@ -317,6 +317,39 @@ def test_kernel_step_program_holds_no_tables():
     assert len(text) < 1_000_000
 
 
+@pytest.mark.parametrize("backend", ["pallas_interpret", "jax"])
+def test_step_programs_name_their_scopes(backend):
+    """The lowered pn16 step programs (the kernel step and the dense jax
+    step) carry named scopes around the forward gather, the conversions,
+    the injection and the decision, which a profile's ``tf_op`` shows,
+    and stay under the same 1 MB with that debug information."""
+    import contextlib
+
+    from repro.jaxenv import x64
+    from repro.sim.engine import init_state, make_step
+    from repro.sim.kernel import make_step_sparse
+    from repro.sim.tables import build_tables
+    g = pn_graph(16)
+    cfg = SimConfig(routing="ugal_threshold(0)")
+    if backend == "jax":
+        dtype, scope = np.float64, x64
+        t = build_tables(g, np.arange(g.n), dtype=dtype)
+        step = make_step(t, cfg, backend, dtype)
+    else:
+        dtype, scope = np.float32, contextlib.nullcontext
+        t = build_tables(g, np.arange(g.n), dtype=dtype)
+        step = make_step_sparse(t, cfg, backend, dtype)
+    state = init_state(t, dtype).as_tuple()
+    with scope():
+        text = step.jitted.lower(step.tabs, state,
+                                 np.zeros((t.n, t.m), dtype),
+                                 np.zeros(t.n, dtype)).as_text(
+                                     debug_info=True)
+    for name in ("forward_gather", "conversions", "injection", "decision"):
+        assert f"/{name}/" in text, name
+    assert len(text) < 1_000_000
+
+
 @pytest.mark.parametrize("backend", ["jax", "pallas_interpret"])
 def test_fault_states_share_one_compile(backend):
     """Two fault states of one Simulator have tables of the same shapes,
