@@ -50,7 +50,7 @@ from ..core.traffic import make_pattern, normalize_demand, saturation_report
 from ..obs import balance_stats
 from .engine import (SIM_JAX_MIN_WORK, SIM_MAX_CELLS, BoundStep, SimConfig,
                      SimState, init_state, make_step, parse_sim_routing,
-                     pick_backend)
+                     pick_backend, state_shapes)
 from .faults import FaultEvent, apply_fault_surgery, normalize_events
 from .kernel import SPARSE_BACKENDS, make_step_sparse, resolve_dtype
 from .tables import RouteTables, build_tables
@@ -232,6 +232,9 @@ class Simulator:
         # fault-state label -> (tables, step); the jitted steps compile
         # once per table shape, so fault states share one program
         self._fault_cache: dict = {}
+        # the last run's final state where its step left it, and its host
+        # copy once `last_state` has been read
+        self._final = self._final_host = None
 
     def _make_step(self, tb):
         if self.backend in SPARSE_BACKENDS:
@@ -255,10 +258,7 @@ class Simulator:
 
     def _place(self, st, sp):
         """Host state ``st`` on the jitted step's device, counted in
-        ``sim.state_put_bytes`` and synced on span ``sp``; the numpy
-        steps read it where it is."""
-        if self._put is None:
-            return st
+        ``sim.state_put_bytes`` and synced on span ``sp``."""
         st = self._put(st)
         sp.sync(st)
         obs.counter("sim.state_put_bytes").add(
@@ -272,6 +272,19 @@ class Simulator:
             obs.counter("sim.state_fetch_bytes").add(
                 float(sum(a.nbytes for a in st)))
         return tuple(np.asarray(a) for a in st)
+
+    @property
+    def last_state(self) -> SimState | None:
+        """The last run's final fluid state as host arrays (None before
+        any run).  A jitted step's state is copied off the device on the
+        first read, in span ``sim.state_fetch``, and cached."""
+        if self._final_host is None and self._final is not None:
+            with obs.span("sim.state_fetch"):
+                self._final_host = SimState(*self._fetch(self._final))
+            if self._put is not None:
+                obs.counter("sim.last_state_fetches").add(1.0)
+            self._final = None
+        return self._final_host
 
     def default_steps(self, events=None) -> int:
         """Enough steps for the slowest feedback loop to settle: several
@@ -380,12 +393,19 @@ class Simulator:
 
             inj = (offered * inj_norm_run).astype(self.dtype)
         # the state lives where the step reads it (the device, for the
-        # jitted steps) from the first step to each segment's end; a
-        # step-0 fault's surgery runs on the host zeros before placement
+        # jitted steps) from its zeros to each segment's end; a step-0
+        # fault's surgery runs on the host zeros before placement.  The
+        # previous run's final state goes first, so it never shares the
+        # device with this run's.
+        self._final = self._final_host = None
         with obs.span("sim.state_put") as sp:
-            st = init_state(t, self.dtype, dest_cols=cols).as_tuple()
-            if segs[0][2] is None:
-                st = self._place(st, sp)
+            if self._put is not None and segs[0][2] is None:
+                st = self._step.zeros(state_shapes(t, cols).as_tuple(),
+                                      self.dtype)
+                sp.sync(st)
+                obs.counter("sim.state_device_zeros").add(1.0)
+            else:
+                st = init_state(t, self.dtype, dest_cols=cols).as_tuple()
         hist = np.empty((steps, 6), dtype=np.float64)
         # per-step surviving-demand total: each fault segment's history
         # is normalized by ITS OWN fault state's surviving demand, not
@@ -475,10 +495,10 @@ class Simulator:
             if s1 < steps:
                 with obs.span("sim.state_fetch"):
                     st = self._fetch(st)
-        # final fluid state, host-side (tests probe buffer occupancies);
-        # the previous run's is dropped in the same span
+        # the final state stays where the step left it: the sweep reads
+        # only the histories, and `last_state` fetches it on demand
         with obs.span("sim.state_fetch"):
-            self.last_state = SimState(*self._fetch(st))
+            self._final = st
 
         with obs.span("sim.run_result"):
             # theta in the FINAL fault state's surviving demand units — the

@@ -48,8 +48,8 @@ import numpy as np
 from .tables import RouteTables
 
 __all__ = ["SimConfig", "SimState", "BoundStep", "make_step", "init_state",
-           "parse_sim_routing", "pick_backend", "SIM_JAX_MIN_WORK",
-           "SIM_MAX_CELLS"]
+           "state_shapes", "parse_sim_routing", "pick_backend",
+           "SIM_JAX_MIN_WORK", "SIM_MAX_CELLS"]
 
 _BIG = 1e12     # unreachable-queue sentinel for masked mins
 _TINY = 1e-30   # safe-division floor
@@ -169,11 +169,16 @@ def init_state(t: RouteTables, dtype, dest_cols=None) -> SimState:
     q2, src, and the pend pool's dest axis — carry only the ``C``
     demanded columns; q1 and stage2 keep the full ``M`` mid axis, since
     Valiant leg-1 fluid is addressed to intermediates."""
+    return SimState(*(np.zeros(s, dtype=dtype)
+                      for s in state_shapes(t, dest_cols).as_tuple()))
+
+
+def state_shapes(t: RouteTables, dest_cols=None) -> SimState:
+    """The shapes of :func:`init_state`'s arrays, as a ``SimState``."""
     n, k, m = t.n, t.k, t.m
     c = m if dest_cols is None else len(dest_cols)
-    z = lambda *s: np.zeros(s, dtype=dtype)
-    return SimState(q0=z(n, k, c), q1=z(n, k, m), q2=z(n, k, c),
-                    src=z(n, c), pend=z(m, c), stage2=z(m))
+    return SimState(q0=(n, k, c), q1=(n, k, m), q2=(n, k, c),
+                    src=(n, c), pend=(m, c), stage2=(m,))
 
 
 # stats vector layout emitted by one step
@@ -243,7 +248,7 @@ class BoundStep:
     ``step(state, inj, inj_cap) = jitted(tabs, state, inj, inj_cap)``.
     The tables are placed once, at construction (span ``sim.table_put``,
     counter ``sim.table_put_bytes``); :meth:`put` places a host state the
-    same way.  With ``scoped_x64`` the placements and every call run
+    same way, and :meth:`zeros` makes a zero state there.  With ``scoped_x64`` the placements and every call run
     under x64, so float64 tables and state stay float64."""
 
     def __init__(self, jitted, tabs, scoped_x64: bool):
@@ -265,9 +270,23 @@ class BoundStep:
         with self._scope():
             return jax.device_put(state)
 
+    def zeros(self, shapes, dtype):
+        """Zero arrays of ``shapes`` made on the step's device by one
+        program: no host buffer, nothing sent."""
+        with self._scope():
+            return _jax_zeros(tuple(map(tuple, shapes)),
+                              np.dtype(dtype).name)()
+
     def __call__(self, state, inj, inj_cap):
         with self._scope():
             return self.jitted(self.tabs, state, inj, inj_cap)
+
+
+@functools.lru_cache(maxsize=16)
+def _jax_zeros(shapes: tuple, dtype: str):
+    import jax
+    import jax.numpy as jnp
+    return jax.jit(lambda: tuple(jnp.zeros(s, dtype) for s in shapes))
 
 
 @functools.lru_cache(maxsize=16)

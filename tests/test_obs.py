@@ -256,15 +256,21 @@ def test_sim_spans_per_build_and_run():
     _assert_nested(sess, "sim.stats_pull", "sim.run", 7)
 
 
+def _state_bytes(sim):
+    from repro.sim.engine import init_state
+    return sum(a.nbytes for a in init_state(
+        sim.tables, sim.dtype, dest_cols=sim.dest_cols).as_tuple())
+
+
 @pytest.mark.parametrize("backend,enabled", [("jax", True), ("jax", False),
                                              ("pallas_interpret", False)])
 def test_sim_byte_counters_match_moved_arrays(backend, enabled):
-    """The byte counters are the bytes of the state ``init_state`` builds
-    (sent and fetched once per run) and of the tables placed once per
+    """A fault-free run makes its zero state on the device and keeps its
+    final state there: no state bytes are sent, and one state comes back
+    per run only where something reads ``last_state`` (an enabled
+    session's balance statistics).  The tables are placed once per
     build, also in a session with ``enabled = False``."""
     import jax
-
-    from repro.sim.engine import init_state
     dem = _uniform(G16)
     with obs.session(mode="trace", series=False) as sess:
         sess.enabled = enabled
@@ -272,13 +278,87 @@ def test_sim_byte_counters_match_moved_arrays(backend, enabled):
                                        backend=backend), demand=dem)
         sim.run(dem, 0.3, steps=3)
         sim.run(dem, 0.4, steps=3)
-    state = sum(a.nbytes for a in init_state(
-        sim.tables, sim.dtype, dest_cols=sim.dest_cols).as_tuple())
+    state = _state_bytes(sim)
     tables = sum(a.nbytes for a in jax.tree.leaves(sim._step.tabs))
     m = sess.metrics
     assert m.counter("sim.table_put_bytes").value == tables > 0
-    assert m.counter("sim.state_put_bytes").value == 2 * state > 0
-    assert m.counter("sim.state_fetch_bytes").value == 2 * state
+    assert m.counter("sim.state_put_bytes").value == 0
+    assert m.counter("sim.state_device_zeros").value == 2
+    reads = 2 if enabled else 0
+    assert m.counter("sim.state_fetch_bytes").value == reads * state
+    assert m.counter("sim.last_state_fetches").value == reads
+
+
+@pytest.mark.parametrize("backend", ["jax", "pallas_interpret"])
+def test_sim_last_state_fetched_once_on_read(backend):
+    """``last_state`` after a device run is, to the bit, the state the
+    same run leaves on the host path; the first read copies it off the
+    device, the second reads the cached copy."""
+    dem = _uniform(G16)
+    sim = Simulator(G16, SimConfig(routing="ugal_threshold(0)",
+                                   backend=backend), demand=dem)
+    put, sim._put = sim._put, None
+    sim.run(dem, 0.5, steps=12)
+    host = sim.last_state
+    sim._put = put
+    with obs.session(mode="trace", series=False) as sess:
+        sess.enabled = False
+        sim.run(dem, 0.5, steps=12)
+        m = sess.metrics
+        assert m.counter("sim.state_fetch_bytes").value == 0
+        first = sim.last_state
+        assert sim.last_state is first
+    assert m.counter("sim.state_fetch_bytes").value == _state_bytes(sim)
+    assert m.counter("sim.last_state_fetches").value == 1
+    assert m.counter("sim.state_device_zeros").value == 1
+    assert sess.span_summary()["sim.state_fetch"]["count"] == 2
+    for a, b in zip(first.as_tuple(), host.as_tuple()):
+        assert type(a) is np.ndarray and a.dtype == b.dtype == sim.dtype
+        np.testing.assert_array_equal(a, b)
+
+
+def test_sim_new_run_releases_previous_state_before_its_zeros():
+    """A run lets go of the previous run's device state, and of its host
+    copy once read, before it makes its own zeros."""
+    import weakref
+    dem = _uniform(G16)
+    sim = Simulator(G16, JAX_UGAL)
+    real, alive, refs = sim._step.zeros, [], []
+
+    def zeros(*args):
+        alive.append([r() is not None for r in refs])
+        return real(*args)
+
+    sim._step.zeros = zeros
+    sim.run(dem, 0.3, steps=3)
+    refs = [weakref.ref(a) for a in sim._final]
+    sim.run(dem, 0.3, steps=3)          # the device state, never read
+    refs = [weakref.ref(a) for a in sim.last_state.as_tuple()]
+    assert sim._final is None
+    sim.run(dem, 0.3, steps=3)          # the cached host copy
+    assert alive == [[], [False] * 6, [False] * 6]
+    assert sim._final_host is None and len(sim._final) == 6
+
+
+@pytest.mark.parametrize("event_step", [0, 5])
+def test_sim_fault_segments_place_state_from_host(event_step):
+    """A fault at step 0 does its surgery on host zeros and places them
+    once; a later fault fetches the state at the segment's end and
+    places it again after the surgery."""
+    dem = _uniform(G16)
+    sim = Simulator(G16, JAX_UGAL)
+    with obs.session(mode="trace", series=False) as sess:
+        sess.enabled = False
+        sim.run(dem, 0.5, steps=12,
+                events=[(event_step, random_faults(G16, k_links=2, seed=0))])
+    state, m = _state_bytes(sim), sess.metrics
+    assert m.counter("sim.state_put_bytes").value == state
+    assert m.counter("sim.state_device_zeros").value == (event_step > 0)
+    assert m.counter("sim.state_fetch_bytes").value == \
+        (state if event_step else 0)
+    spans = sess.span_summary()
+    assert spans["sim.state_put"]["count"] == 2
+    assert spans["sim.state_fetch"]["count"] == (2 if event_step else 1)
 
 
 def test_sim_numpy_backend_moves_nothing():
