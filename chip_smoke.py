@@ -121,7 +121,8 @@ def phase_main(q: int = 27, steps: int = 30, refine: int = 2,
     inj = dem[:, t.active] if cols is None else dem[:, t.active[cols]]
     inj = (0.9 * ref * inj).astype(sim.dtype)
     inj_cap = inj.sum(axis=1)
-    state = init_state(t, sim.dtype, dest_cols=cols).as_tuple()
+    state = sim._step.put(
+        init_state(t, sim.dtype, dest_cols=cols).as_tuple())
     compile_s = []
 
     def listen(event, duration, **kw):

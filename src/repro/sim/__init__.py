@@ -226,9 +226,15 @@ class Simulator:
             self._step = self._make_step(self.tables)
         # the jitted steps read their state on the device: host states
         # are placed explicitly (sim.state_put), never inside a step call
-        self._put = (self._step.put if isinstance(self._step, BoundStep)
-                     else None)
+        bound = isinstance(self._step, BoundStep)
+        self._put = self._step.put if bound else None
         obs.counter(f"sim.backend[{self.backend}]").add(1.0)
+        # per build, so that a ratio of two reads the same in any session;
+        # the step may lay its state at empty out-slots past the graph's K
+        obs.counter("sim.arcs").add(float(len(g.indices)))
+        obs.counter("sim.out_slots").add(float(self.tables.k))
+        obs.counter("sim.out_slots_laid").add(
+            float(self.tables.k + (self._step.pad if bound else 0)))
         # fault-state label -> (tables, step); the jitted steps compile
         # once per table shape, so fault states share one program
         self._fault_cache: dict = {}
@@ -265,13 +271,18 @@ class Simulator:
             float(sum(a.nbytes for a in st)))
         return st
 
+    def _view(self, st):
+        """The step's state ``st`` with the graph's K out-slots."""
+        return st if self._put is None else self._step.view(st)
+
     def _fetch(self, st):
-        """State ``st`` as host arrays; what comes off the jitted step's
-        device is counted in ``sim.state_fetch_bytes``."""
+        """State ``st`` as host arrays with the graph's K out-slots; what
+        comes off the jitted step's device is counted in
+        ``sim.state_fetch_bytes``."""
         if self._put is not None:
             obs.counter("sim.state_fetch_bytes").add(
                 float(sum(a.nbytes for a in st)))
-        return tuple(np.asarray(a) for a in st)
+        return tuple(np.asarray(a) for a in self._view(st))
 
     @property
     def last_state(self) -> SimState | None:
@@ -480,12 +491,16 @@ class Simulator:
                     st, stats = step_fn(st, inj_seg, inj_cap)
                 with obs.span("sim.stats_pull"):
                     hist[i] = np.asarray(stats, dtype=np.float64)
+                if (cap is not None or mon is not None
+                        or per_dest and i >= win_start):
+                    # what the observers read: the graph's K out-slots
+                    vs = self._view(st)
                 if cap is not None:
-                    cap.on_step(i, st, hist[i])
+                    cap.on_step(i, vs, hist[i])
                 if mon is not None:
-                    mon.on_step(i, st, hist[i])
+                    mon.on_step(i, vs, hist[i])
                 if per_dest and i >= win_start:
-                    dm = _dest_mass_host(st)
+                    dm = _dest_mass_host(vs)
                     if pd_mass0 is None:
                         pd_mass0 = dm
                         pd_off = np.zeros_like(dm)

@@ -48,7 +48,7 @@ import numpy as np
 from .tables import RouteTables
 
 __all__ = ["SimConfig", "SimState", "BoundStep", "make_step", "init_state",
-           "state_shapes", "parse_sim_routing", "pick_backend",
+           "state_shapes", "pad_slots", "parse_sim_routing", "pick_backend",
            "SIM_JAX_MIN_WORK", "SIM_MAX_CELLS"]
 
 _BIG = 1e12     # unreachable-queue sentinel for masked mins
@@ -181,6 +181,20 @@ def state_shapes(t: RouteTables, dest_cols=None) -> SimState:
                     src=(n, c), pend=(m, c), stage2=(m,))
 
 
+def pad_slots(a: np.ndarray, pad: int, dtype=None, fill=0) -> np.ndarray:
+    """``a`` (N, K, ...) at ``dtype`` (default its own) with ``pad`` slots
+    of ``fill`` appended to its out-slot axis, cast and padded in one
+    copy; ``a`` itself where it needs neither."""
+    if not pad:
+        return np.asarray(a, dtype=dtype)
+    k = a.shape[1]
+    out = np.empty((a.shape[0], k + pad) + a.shape[2:],
+                   dtype=a.dtype if dtype is None else dtype)
+    out[:, :k] = a
+    out[:, k:] = fill
+    return out
+
+
 # stats vector layout emitted by one step
 STAT_NAMES = ("delivered", "accepted", "offered", "occupancy",
               "src_backlog", "diverted")
@@ -248,15 +262,20 @@ class BoundStep:
     ``step(state, inj, inj_cap) = jitted(tabs, state, inj, inj_cap)``.
     The tables are placed once, at construction (span ``sim.table_put``,
     counter ``sim.table_put_bytes``); :meth:`put` places a host state the
-    same way, and :meth:`zeros` makes a zero state there.  With ``scoped_x64`` the placements and every call run
-    under x64, so float64 tables and state stay float64."""
+    same way, and :meth:`zeros` makes a zero state there.  The step may
+    lay the out-slot axis of its queue tensors (q0, q1, q2) at ``pad``
+    empty slots past the graph's K: :meth:`put` and :meth:`zeros` take a
+    state at K and lay it out so, and :meth:`view` cuts it back.  With
+    ``scoped_x64`` the placements and every call run under x64, so
+    float64 tables and state stay float64."""
 
-    def __init__(self, jitted, tabs, scoped_x64: bool):
+    def __init__(self, jitted, tabs, scoped_x64: bool, pad: int = 0):
         import jax
 
         from .. import obs
         from ..jaxenv import x64
         self.jitted = jitted
+        self.pad = pad
         self._scope = x64 if scoped_x64 else contextlib.nullcontext
         with self._scope(), obs.span("sim.table_put") as sp:
             self.tabs = jax.device_put(tabs)
@@ -265,17 +284,30 @@ class BoundStep:
             float(sum(a.nbytes for a in jax.tree.leaves(self.tabs))))
 
     def put(self, state):
-        """``state`` (a pytree of host arrays) on the step's device."""
+        """Host ``state`` (a pytree of host arrays) on the step's device,
+        laid out."""
         import jax
         with self._scope():
-            return jax.device_put(state)
+            return jax.device_put(tuple(
+                pad_slots(a, self.pad) if j < 3 else a
+                for j, a in enumerate(state)))
 
     def zeros(self, shapes, dtype):
-        """Zero arrays of ``shapes`` made on the step's device by one
-        program: no host buffer, nothing sent."""
+        """Zero arrays of a state's ``shapes``, laid out, made on the
+        step's device by one program: no host buffer, nothing sent."""
+        shapes = tuple((s[0], s[1] + self.pad, *s[2:]) if j < 3
+                       else tuple(s) for j, s in enumerate(shapes))
         with self._scope():
-            return _jax_zeros(tuple(map(tuple, shapes)),
-                              np.dtype(dtype).name)()
+            return _jax_zeros(shapes, np.dtype(dtype).name)()
+
+    def view(self, state):
+        """A laid-out ``state`` with the graph's K out-slots: ``state``
+        itself where the step lays no empty slots, else host arrays whose
+        queue tensors are cut back to K (views)."""
+        if not self.pad:
+            return state
+        return tuple(np.asarray(a)[:, :-self.pad] if j < 3
+                     else np.asarray(a) for j, a in enumerate(state))
 
     def __call__(self, state, inj, inj_cap):
         with self._scope():

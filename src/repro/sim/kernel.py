@@ -67,10 +67,10 @@ from typing import NamedTuple
 
 import numpy as np
 
-from .engine import _BIG, _TINY, BoundStep, SimConfig
+from .engine import _BIG, _TINY, BoundStep, SimConfig, pad_slots
 from .tables import RouteTables
 
-__all__ = ["make_step_sparse", "step_aux", "resolve_dtype",
+__all__ = ["make_step_sparse", "step_aux", "resolve_dtype", "laid_slots",
            "SPARSE_BACKENDS"]
 
 SPARSE_BACKENDS = ("pallas", "pallas_interpret")
@@ -78,6 +78,10 @@ SPARSE_BACKENDS = ("pallas", "pallas_interpret")
 # dest-tile width shared with the pallas kernel (repro.kernels.sim_step,
 # imported lazily: the numpy path never loads jax)
 DEST_TILE = 128
+
+# the TPU's sublane count: the kernel step lays the out-slot axis of its
+# state and tables at a multiple of it (see laid_slots)
+SUBLANE = 8
 
 # live queue cells per step below which the slab loop stays serial:
 # thread spawn/join per wave costs ~0.1 ms, which only pays for itself
@@ -217,6 +221,16 @@ def step_aux(t: RouteTables, tile: int = DEST_TILE) -> _StepAux:
         aux = _StepAux(t, tile)
         t._step_aux = aux
     return aux
+
+
+def laid_slots(k: int) -> int:
+    """Out-slots the kernel step lays its (N, K, W) state and tables at:
+    K rounded up to the sublane multiple.  At any other K the TPU keeps
+    such an array with N as its second-minor axis, while the kernels'
+    blocks need K there, so every step would copy and relayout the
+    state and the split and deliver tables around the kernels.  The
+    extra slots are empty: head N, zero split and deliver, no fluid."""
+    return -(-k // SUBLANE) * SUBLANE
 
 
 def resolve_dtype(name: str, backend: str):
@@ -643,7 +657,7 @@ class _KernelSpec(NamedTuple):
     lengths) share one compiled step."""
 
     n: int
-    k: int
+    k: int                 # out-slots as laid (laid_slots of the graph's)
     widths: tuple          # per-VC dest-axis width (C, M, C)
     compact: bool          # q0/q2 carry the compacted C axis
     mode: str
@@ -658,17 +672,20 @@ class _KernelSpec(NamedTuple):
 def _kernel_tables(t: RouteTables, dtype, dest_cols=None) -> dict:
     """Host-side arrays the kernel step reads: the (N, K, W) split and
     deliver tables of each dest axis, the (N, W) decision tables, and
-    the arc-index structure (int32)."""
+    the arc-index structure (int32), all with the out-slot axis laid at
+    :func:`laid_slots` and arcs numbered ``router * laid + slot``."""
     aux = step_aux(t)
     n, k = t.n, t.k
-    nk = n * k
+    kl = laid_slots(k)
+    pad, nk = kl - k, n * kl
+    lay = lambda a: a // k * kl + a % k        # arc number, laid
     asd = lambda a: np.asarray(a, dtype=dtype)
     idx = lambda a: np.asarray(a, dtype=np.int32)
 
     def axis_tables(ax, sel):
-        return {"split": asd(t.split[:, :, sel]),
-                "deliver": asd(t.deliver[:, :, sel]),
-                "fix_arc": idx(ax.fix_arc), "fix_dst": idx(ax.fix_dst),
+        return {"split": pad_slots(t.split[:, :, sel], pad, dtype),
+                "deliver": pad_slots(t.deliver[:, :, sel], pad, dtype),
+                "fix_arc": idx(lay(ax.fix_arc)), "fix_dst": idx(ax.fix_dst),
                 "fix_router": idx(ax.fix_router),
                 "dst_router": idx(ax.dst_router),
                 "dst_col": idx(ax.dst_col)}
@@ -682,11 +699,14 @@ def _kernel_tables(t: RouteTables, dtype, dest_cols=None) -> dict:
         "F": axis_tables(_DestAxis(aux), slice(None)),
         "dist_c": asd(t.dist_act[:, sel]), "hval_c": asd(t.hval_rem[:, sel]),
         "spread": asd(t.spread),
-        "w_val": asd(np.einsum("nm,nkm->nk", t.spread, t.split)),
+        "w_val": pad_slots(np.einsum("nm,nkm->nk", t.spread, t.split), pad,
+                           dtype),
         "spread_T": asd(t.spread.T), "n_mids": asd(t.m - in_active),
-        "active": idx(t.active), "head_flat": idx(t.head.reshape(-1)),
+        "active": idx(t.active),
+        "head_flat": pad_slots(t.head, pad, np.int32, fill=n).reshape(-1),
         # reverse-arc gather: sentinel -> the appended zero row
-        "rev": idx(np.where(aux.rev >= 0, aux.rev, nk).reshape(n, k)),
+        "rev": pad_slots(np.where(aux.rev >= 0, lay(aux.rev),
+                                  nk).reshape(n, k), pad, np.int32, fill=nk),
         "diag_mid": idx(diag_mid), "diag_col": idx(diag_col),
     }
     if dest_cols is not None:
@@ -698,10 +718,12 @@ def kernel_program(t: RouteTables, cfg: SimConfig, dtype, interpret,
                    dest_cols=None):
     """``(jitted, tabs)``: the compiled-once kernel step
     ``jitted(tabs, state, inj, inj_cap)`` and the host-side route tables
-    it takes as its first argument."""
+    it takes as its first argument; its state's out-slot axis is laid at
+    :func:`laid_slots` of ``t.k``."""
     from .. import obs
     c = t.m if dest_cols is None else len(dest_cols)
-    spec = _KernelSpec(t.n, t.k, (c, t.m, c), dest_cols is not None,
+    spec = _KernelSpec(t.n, laid_slots(t.k), (c, t.m, c),
+                       dest_cols is not None,
                        cfg.mode, cfg.threshold, float(cfg.capacity),
                        float(min(cfg.buffer, _BIG)),
                        bool(getattr(t, "faulted", False)),
@@ -714,7 +736,8 @@ def kernel_program(t: RouteTables, cfg: SimConfig, dtype, interpret,
 def _make_step_kernel(t: RouteTables, cfg: SimConfig, dtype, interpret,
                       dest_cols=None):
     jitted, tabs = kernel_program(t, cfg, dtype, interpret, dest_cols)
-    return BoundStep(jitted, tabs, scoped_x64=dtype == np.float64)
+    return BoundStep(jitted, tabs, scoped_x64=dtype == np.float64,
+                     pad=laid_slots(t.k) - t.k)
 
 
 @functools.lru_cache(maxsize=16)

@@ -21,7 +21,7 @@ import numpy as np
 import pytest
 
 from repro import obs
-from repro.core import pn_graph, random_faults
+from repro.core import mms_graph, pn_graph, random_faults
 from repro.fabric.model import torus3d_graph
 from repro.obs import MetricsRegistry, balance_stats
 from repro.sim import SimConfig, Simulator
@@ -225,6 +225,32 @@ def test_sim_series_capture_under_trace():
     assert "sim.run" in names and "sim.build_tables" in names
 
 
+def test_sim_series_capture_on_a_laid_out_kernel_step():
+    """Series capture under a trace session on the kernel step at Slim
+    Fly MMS(5), whose 7 out-slots the step lays at 8: the per-step
+    observers see the graph's K, so the measured link utilization has
+    one entry per arc and matches the dense float64 oracle's."""
+    g = mms_graph(5)
+    dem = _uniform(g)
+    util, stab = {}, {}
+    for backend in ("numpy", "pallas_interpret"):
+        with obs.session(mode="trace") as sess:
+            sim = Simulator(g, SimConfig(routing="ugal_threshold(0)",
+                                         backend=backend, dtype="float64"),
+                            demand=dem)
+            sim.run(dem, offered=2.0, steps=40, window=10)
+        m = sess.metrics
+        util[backend] = m.histogram("sim.link_util").values
+        stab[backend] = m.histogram("sim.dest_stability").values
+        assert len(m.series("sim.occ_vc0")) == 40
+    assert sim.tables.k == 7 and sim._step.pad == 1
+    assert len(util["pallas_interpret"]) == len(g.indices)
+    np.testing.assert_allclose(util["pallas_interpret"], util["numpy"],
+                               rtol=1e-7, atol=1e-12)
+    np.testing.assert_allclose(stab["pallas_interpret"], stab["numpy"],
+                               rtol=1e-7)
+
+
 # -- the Simulator's host spans, byte counters and profiler annotations ----
 
 G16 = torus3d_graph(4, 4, 1)
@@ -257,9 +283,10 @@ def test_sim_spans_per_build_and_run():
 
 
 def _state_bytes(sim):
-    from repro.sim.engine import init_state
-    return sum(a.nbytes for a in init_state(
-        sim.tables, sim.dtype, dest_cols=sim.dest_cols).as_tuple())
+    """Bytes of one run's state as its step lays it out."""
+    from repro.sim.engine import state_shapes
+    return sum(a.nbytes for a in sim._step.zeros(
+        state_shapes(sim.tables, sim.dest_cols).as_tuple(), sim.dtype))
 
 
 @pytest.mark.parametrize("backend,enabled", [("jax", True), ("jax", False),
@@ -297,10 +324,13 @@ def test_sim_last_state_fetched_once_on_read(backend):
     dem = _uniform(G16)
     sim = Simulator(G16, SimConfig(routing="ugal_threshold(0)",
                                    backend=backend), demand=dem)
-    put, sim._put = sim._put, None
+    # the host path: zeros made on the host and placed, as the step
+    # lays them out (the kernel step lays G16's 4 out-slots at 8)
+    sim._step.zeros = lambda shapes, dtype: sim._step.put(
+        tuple(np.zeros(s, dtype) for s in shapes))
     sim.run(dem, 0.5, steps=12)
     host = sim.last_state
-    sim._put = put
+    del sim._step.zeros
     with obs.session(mode="trace", series=False) as sess:
         sess.enabled = False
         sim.run(dem, 0.5, steps=12)

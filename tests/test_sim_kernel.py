@@ -27,7 +27,7 @@ from __future__ import annotations
 import numpy as np
 import pytest
 
-from repro.core import pn_graph, random_faults
+from repro.core import mms_graph, pn_graph, random_faults
 from repro.core.traffic import make_pattern, normalize_demand
 from repro.core.utilization import arc_loads, arc_loads_weighted
 from repro.fabric.model import torus3d_graph
@@ -36,6 +36,10 @@ from repro.sim.kernel import SPARSE_BACKENDS, resolve_dtype
 
 G16 = torus3d_graph(4, 4, 1)
 PN3 = pn_graph(3)
+# Slim Fly MMS(5) and MMS(7): K = 7 and 11, off the sublane multiple that
+# the kernel step lays its out-slot axis at
+MMS5, MMS7 = mms_graph(5), mms_graph(7)
+ROUTINGS = ["minimal", "valiant", "ugal_threshold(0)"]
 
 
 def _uniform(g):
@@ -72,34 +76,92 @@ def _run_backend(g, demand, backend, routing="minimal", offered=0.5,
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("routing", ["minimal", "valiant",
-                                     "ugal_threshold(0)"])
-def test_fused_numpy_matches_dense_float64(routing):
+@pytest.mark.parametrize(
+    "g,routing",
+    [(G16, r) for r in ROUTINGS]
+    + [(g, r) for g in (MMS5, MMS7) for r in ROUTINGS],
+    ids=ROUTINGS + [f"{g}-{r}" for g in ("mms5", "mms7") for r in ROUTINGS])
+def test_fused_numpy_matches_dense_float64(g, routing):
     """The CPU 'pallas' backend (blocked sparse-dest numpy) against the
     dense oracle, all routing modes, float64: round-off-level identity."""
-    dem = _uniform(G16)
-    a = _run_backend(G16, dem, "numpy", routing, offered=0.7)
-    b = _run_backend(G16, dem, "pallas", routing, offered=0.7)
+    dem = _uniform(g)
+    offered = 0.7 * g.max_degree / G16.max_degree
+    a = _run_backend(g, dem, "numpy", routing, offered=offered)
+    b = _run_backend(g, dem, "pallas", routing, offered=offered)
     _histories_close(a, b, rtol=1e-9)
     assert a.residual < 1e-9 and b.residual < 1e-9
 
 
-@pytest.mark.parametrize("seed", [0, 1])
-def test_interpret_kernel_parity_random_demand(seed):
+@pytest.mark.parametrize("g,seed", [(G16, 0), (G16, 1), (MMS5, 0),
+                                    (MMS7, 0)],
+                         ids=["0", "1", "mms5-0", "mms7-0"])
+def test_interpret_kernel_parity_random_demand(g, seed):
     """The ACTUAL pallas kernel (interpret mode) against the dense numpy
-    oracle on random demand with finite buffers and a mid-run fault —
-    the ISSUE's property test, float64 end to end."""
-    dem = _random_demand(G16, seed)
-    fs = random_faults(G16, k_links=3, seed=seed)
+    oracle on random demand with finite buffers and a mid-run fault,
+    float64 end to end; on MMS(5) and MMS(7) with the kernel step's
+    out-slot axis padded to 8 and 16."""
+    dem = _random_demand(g, seed)
+    fs = random_faults(g, k_links=3, seed=seed)
     kw = dict(routing="ugal_threshold(0)", offered=0.6, steps=24,
               buffer=6.0, events=[(8, fs)])
-    a = _run_backend(G16, dem, "numpy", **kw)
-    b = _run_backend(G16, dem, "pallas_interpret", **kw)
+    a = _run_backend(g, dem, "numpy", **kw)
+    b = _run_backend(g, dem, "pallas_interpret", **kw)
     # the kernel's TPU summation order differs from the dense einsum's;
     # the threshold rule amplifies that round-off through its diversion
     # decisions, so float64 agreement is ~1e-8, not 1e-15
     _histories_close(a, b, rtol=1e-6, atol=1e-9)
     assert b.residual < 1e-7
+
+
+def test_last_state_shows_the_graphs_out_slots():
+    """The kernel step lays MMS(5)'s 7 out-slots at 8; ``last_state``
+    shows callers the graph's 7, and equals the dense oracle's state."""
+    from repro.sim.kernel import laid_slots
+    dem = _uniform(MMS5)
+    runs = {}
+    for backend in ("numpy", "pallas_interpret"):
+        sim = Simulator(MMS5, SimConfig(routing="ugal_threshold(0)",
+                                        backend=backend, dtype="float64"),
+                        demand=dem)
+        sim.run(dem, 3.0, steps=12)
+        runs[backend] = (sim, sim.last_state)
+    sim, st = runs["pallas_interpret"]
+    assert MMS5.max_degree == sim.tables.k == 7
+    assert 7 + sim._step.pad == laid_slots(7) == 8
+    assert sim._step.tabs["F"]["split"].shape == (MMS5.n, 8, MMS5.n)
+    want = runs["numpy"][1]
+    for name in ("q0", "q1", "q2"):
+        got = getattr(st, name)
+        assert got.shape == (MMS5.n, 7, MMS5.n), name
+        np.testing.assert_allclose(got, getattr(want, name), rtol=1e-9,
+                                   atol=1e-12, err_msg=name)
+
+
+def test_sublane_multiple_degrees_get_no_padding():
+    """At K = 32, the out-slot count of PN(31) (here the complete graph
+    on 33 routers), the kernel step's state and tables keep the graph's
+    K and the tables are the route tables unchanged."""
+    from repro.core.graph import Graph
+    from repro.sim.kernel import kernel_program, laid_slots, step_aux
+    from repro.sim.tables import build_tables
+    assert [laid_slots(k) for k in (24, 32, 41)] == [24, 32, 48]
+    n = 33
+    i, j = np.triu_indices(n, 1)
+    g = Graph(n, np.column_stack([i, j]))
+    t = build_tables(g, np.arange(n), dtype=np.float32)
+    assert t.k == 32
+    cfg = SimConfig(routing="ugal_threshold(0)")
+    jitted, tabs = kernel_program(t, cfg, np.float32, interpret=True)
+    np.testing.assert_array_equal(tabs["F"]["split"], t.split)
+    np.testing.assert_array_equal(tabs["F"]["deliver"], t.deliver)
+    np.testing.assert_array_equal(tabs["head_flat"], t.head.reshape(-1))
+    aux = step_aux(t)
+    np.testing.assert_array_equal(tabs["F"]["fix_arc"], aux.fix_arc)
+    np.testing.assert_array_equal(
+        tabs["rev"], np.where(aux.rev >= 0, aux.rev, n * 32).reshape(n, 32))
+    sim = Simulator(g, SimConfig(routing="ugal_threshold(0)",
+                                 backend="pallas_interpret"))
+    assert sim._step.pad == 0
 
 
 def test_sparse_dest_compaction_is_exact():
@@ -304,14 +366,17 @@ def test_kernel_step_program_holds_no_tables():
     """The lowered pn16 kernel step takes the route tables as an
     argument: its text stays far below the ~86 MB it had when the
     (546, 17, 546) tables were inlined as literals."""
-    from repro.sim.engine import init_state
-    from repro.sim.kernel import kernel_program
+    from repro.sim.engine import init_state, pad_slots
+    from repro.sim.kernel import kernel_program, laid_slots
     from repro.sim.tables import build_tables
     g = pn_graph(16)
     t = build_tables(g, np.arange(g.n), dtype=np.float32)
     jitted, tabs = kernel_program(t, SimConfig(routing="ugal_threshold(0)"),
                                   np.float32, interpret=True)
-    state = init_state(t, np.float32).as_tuple()
+    # the program takes its state laid out: K = 17 at 24 out-slots
+    pad = laid_slots(t.k) - t.k
+    state = tuple(pad_slots(a, pad) if a.ndim == 3 else a
+                  for a in init_state(t, np.float32).as_tuple())
     text = jitted.lower(tabs, state, np.zeros((t.n, t.m), np.float32),
                         np.zeros(t.n, np.float32)).as_text()
     assert len(text) < 1_000_000
@@ -326,7 +391,7 @@ def test_step_programs_name_their_scopes(backend):
     import contextlib
 
     from repro.jaxenv import x64
-    from repro.sim.engine import init_state, make_step
+    from repro.sim.engine import make_step, state_shapes
     from repro.sim.kernel import make_step_sparse
     from repro.sim.tables import build_tables
     g = pn_graph(16)
@@ -339,7 +404,7 @@ def test_step_programs_name_their_scopes(backend):
         dtype, scope = np.float32, contextlib.nullcontext
         t = build_tables(g, np.arange(g.n), dtype=dtype)
         step = make_step_sparse(t, cfg, backend, dtype)
-    state = init_state(t, dtype).as_tuple()
+    state = step.zeros(state_shapes(t).as_tuple(), dtype)
     with scope():
         text = step.jitted.lower(step.tabs, state,
                                  np.zeros((t.n, t.m), dtype),

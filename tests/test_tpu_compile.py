@@ -7,7 +7,7 @@ tiling the chip cannot lay out, a program larger than device memory.
 The shapes are the simulator's own: pn16 ``(546, 17, 546)``, PN(27)
 with its dest axis compacted to the 757 points ``(1514, 28, 757)`` and
 whole ``(1514, 28, 1514)``, and the paper's Table-5 PN(31)
-``(1986, 32, 1986)``.
+``(1986, 32, 1986)`` and Slim Fly MMS(27) ``(1458, 41, 1458)``.
 
 The topology is described inside a module fixture, never at import: only
 one process may hold the TPU library, and every test worker imports this
@@ -24,7 +24,7 @@ import pytest
 HBM_BYTES = 16 * 10**9          # one TPU v5e chip
 
 STEP_SHAPES = [(546, 17, 546), (1514, 28, 757), (1514, 28, 1514),
-               (1986, 32, 1986)]
+               (1986, 32, 1986), (1458, 41, 1458)]
 SHAPE_IDS = ["x".join(map(str, s)) for s in STEP_SHAPES]
 
 
@@ -100,8 +100,8 @@ def test_kernel_step_compiles_within_hbm(one_chip, q):
 
     from repro.core import pn_graph
     from repro.sim import SimConfig
-    from repro.sim.engine import init_state
-    from repro.sim.kernel import kernel_program
+    from repro.sim.engine import init_state, pad_slots
+    from repro.sim.kernel import kernel_program, laid_slots
     from repro.sim.tables import build_tables
 
     g = pn_graph(q)
@@ -110,13 +110,84 @@ def test_kernel_step_compiles_within_hbm(one_chip, q):
     cfg = SimConfig(routing="ugal_threshold(0)")
     jitted, tabs = kernel_program(t, cfg, np.float32, interpret=False,
                                   dest_cols=cols)
-    state = init_state(t, np.float32, dest_cols=cols).as_tuple()
+    # the state as the step lays it out (K rounded up to 8)
+    pad = laid_slots(t.k) - t.k
+    state = tuple(pad_slots(a, pad) if a.ndim == 3 else a
+                  for a in init_state(t, np.float32,
+                                      dest_cols=cols).as_tuple())
     c = t.m if cols is None else len(cols)
     inj, cap = np.zeros((t.n, c), np.float32), np.zeros(t.n, np.float32)
     shapes = jax.tree.map(lambda a: _spec(one_chip, a.shape, a.dtype),
                           (tabs, state, inj, cap))
     compiled = jitted.lower(*shapes).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes)
+    assert 0 < total < HBM_BYTES, total
+
+
+def _moves(hlo: str, shape: str) -> list:
+    """Names of the copies and transposes in ``hlo`` that yield ``shape``."""
+    import re
+    out = []
+    for line in hlo.splitlines():
+        name, _, rhs = line.strip().partition(" = ")
+        op = re.search(r"(?:^| )([a-z][\w-]*)\(", rhs)
+        if (op and op.group(1) in ("copy", "copy-start", "transpose")
+                and shape in rhs[:op.start()]):
+            out.append(name)
+    return out
+
+
+def test_mms27_step_keeps_the_kernels_layout(one_chip):
+    """The whole kernel step at the Table-5 Slim Fly MMS(27) (1458
+    routers, K = 41, every router a destination), from the shapes alone:
+    no (N, K, M)-sized f32 array is copied or relaid around the kernels,
+    which the step did while its out-slot axis sat at 41 (three state
+    tensors and the split and deliver tables copied, three outputs
+    relaid, on every call), and the program fits one chip."""
+    import re
+
+    import jax
+
+    from repro.core import mms_graph
+    from repro.sim import SimConfig
+    from repro.sim.kernel import (_KernelSpec, _kernel_step, kernel_program,
+                                  laid_slots)
+    from repro.sim.tables import build_tables
+
+    n, k, m = 1458, 41, 1458
+    kl = laid_slots(k)
+    arcs = n * k
+    f32 = lambda *sh: _spec(one_chip, sh)
+    i32 = lambda *sh: _spec(one_chip, sh, np.int32)
+    full = {"split": f32(n, kl, m), "deliver": f32(n, kl, m),
+            "fix_arc": i32(arcs), "fix_dst": i32(arcs),
+            "fix_router": i32(arcs), "dst_router": i32(n), "dst_col": i32(n)}
+    tabs = {"F": full, "dist_c": f32(n, m), "hval_c": f32(n, m),
+            "spread": f32(n, m), "w_val": f32(n, kl), "spread_T": f32(m, n),
+            "n_mids": f32(n), "active": i32(m), "head_flat": i32(n * kl),
+            "rev": i32(n, kl), "diag_mid": i32(m), "diag_col": i32(m)}
+    state = (f32(n, kl, m), f32(n, kl, m), f32(n, kl, m), f32(n, m),
+             f32(m, m), f32(m))
+    cfg = SimConfig(routing="ugal_threshold(0)")
+    # the same tables as the program builds them, at MMS(5)'s size
+    t5 = build_tables(mms_graph(5), np.arange(50), dtype=np.float32)
+    _, tabs5 = kernel_program(t5, cfg, np.float32, interpret=False)
+    assert jax.tree.structure(tabs5) == jax.tree.structure(tabs)
+    assert ([(a.ndim, a.dtype) for a in jax.tree.leaves(tabs5)]
+            == [(a.ndim, a.dtype) for a in jax.tree.leaves(tabs)])
+    spec = _KernelSpec(n, kl, (m, m, m), False, cfg.mode, cfg.threshold,
+                       1.0, 1e12, False, "float32", False)
+    assert kl == 48
+    compiled = _kernel_step(spec).lower(tabs, state, f32(n, m),
+                                        f32(n)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    big = re.compile(rf"f32\[{n},{kl},{m}\]{{(\d,\d,\d)[:}}]")
+    assert set(big.findall(text)) == {"2,1,0"}
+    assert _moves(text, f"f32[{n},{kl},{m}]") == []
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes)
