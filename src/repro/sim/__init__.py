@@ -52,7 +52,8 @@ from .engine import (SIM_JAX_MIN_WORK, SIM_MAX_CELLS, BoundStep, SimConfig,
                      SimState, init_state, make_step, parse_sim_routing,
                      pick_backend, state_shapes)
 from .faults import FaultEvent, apply_fault_surgery, normalize_events
-from .kernel import SPARSE_BACKENDS, make_step_sparse, resolve_dtype
+from .kernel import (SPARSE_BACKENDS, make_step_sparse, resolve_dtype,
+                     step_planes)
 from .tables import RouteTables, build_tables
 
 __all__ = [
@@ -221,9 +222,7 @@ class Simulator:
         self.dtype = resolve_dtype(config.dtype, self.backend)
         with obs.span("sim.build_tables", backend=self.backend, n=g.n,
                       dests=len(self.active)):
-            with obs.span("sim.route_tables"):
-                self.tables = build_tables(g, self.active, dtype=self.dtype)
-            self._step = self._make_step(self.tables)
+            self.tables, self._step = self._build()
         # the jitted steps read their state on the device: host states
         # are placed explicitly (sim.state_put), never inside a step call
         bound = isinstance(self._step, BoundStep)
@@ -242,6 +241,19 @@ class Simulator:
         # copy once `last_state` has been read
         self._final = self._final_host = None
 
+    def _build(self, fs=None):
+        """``(tables, step)`` for fault state ``fs``.  Span
+        ``sim.route_tables`` holds the host tables and the dense planes
+        a jitted step reads, made on the device (``step_planes``)."""
+        with obs.span("sim.route_tables") as sp:
+            tb = build_tables(self.g, self.active, dtype=self.dtype,
+                              faults=fs)
+            planes = step_planes(tb, self.backend, self.dtype,
+                                 self.dest_cols)
+            if planes is not None:
+                sp.sync(planes)
+        return tb, self._make_step(tb)
+
     def _make_step(self, tb):
         if self.backend in SPARSE_BACKENDS:
             return make_step_sparse(tb, self.config, self.backend,
@@ -256,10 +268,7 @@ class Simulator:
         key = fs.label
         if key not in self._fault_cache:
             with obs.span("sim.fault_tables", label=key):
-                with obs.span("sim.route_tables"):
-                    tb = build_tables(self.g, self.active, dtype=self.dtype,
-                                      faults=fs)
-                self._fault_cache[key] = (tb, self._make_step(tb))
+                self._fault_cache[key] = self._build(fs)
         return self._fault_cache[key]
 
     def _place(self, st, sp):

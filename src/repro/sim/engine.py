@@ -45,7 +45,7 @@ from typing import NamedTuple
 
 import numpy as np
 
-from .tables import RouteTables
+from .tables import RouteTables, device_planes
 
 __all__ = ["SimConfig", "SimState", "BoundStep", "make_step", "init_state",
            "state_shapes", "pad_slots", "parse_sim_routing", "pick_backend",
@@ -216,20 +216,25 @@ class _DenseSpec(NamedTuple):
     dtype: str
 
 
-def _dense_tables(t: RouteTables, dtype) -> dict:
-    """The route tables one dense step reads, at the state dtype."""
+def _dense_tables(t: RouteTables, dtype, planes=None) -> dict:
+    """The route tables one dense step reads, at the state dtype.  The
+    dense planes are ``planes`` (:func:`repro.sim.tables.device_planes`
+    at the graph's K) where given, else made from the host tables."""
     asd = lambda a: np.asarray(a, dtype=dtype)
+    if planes is None:
+        planes = {"F": {"split": asd(t.split), "deliver": asd(t.deliver)},
+                  "spread": asd(t.spread),
+                  # expected first-hop slot usage of freshly diverted
+                  # fluid: the spread over intermediates pushed through
+                  # the ECMP split (rows sum to 1)
+                  "w_val": asd(np.einsum("nm,nkm->nk", t.spread, t.split))}
     # mids available to a diverting router: m - 1 inside the active set
     # (never via itself), all m mids from a transit-only (spine) router
     in_active = np.zeros(t.n, dtype=bool)
     in_active[t.active] = True
     return {
-        "split": asd(t.split), "deliver": asd(t.deliver),
-        "spread": asd(t.spread),
-        # expected first-hop slot usage of freshly diverted fluid: the
-        # spread over intermediates pushed through the ECMP split (rows
-        # sum to 1)
-        "w_val": asd(np.einsum("nm,nkm->nk", t.spread, t.split)),
+        "split": planes["F"]["split"], "deliver": planes["F"]["deliver"],
+        "spread": planes["spread"], "w_val": planes["w_val"],
         "dist_act": asd(t.dist_act), "hval_rem": asd(t.hval_rem),
         "head_flat": t.head.reshape(-1), "active": t.active,
         "n_mids": asd(t.m - in_active),
@@ -243,15 +248,18 @@ def make_step(t: RouteTables, cfg: SimConfig, backend: str, dtype):
     the (N,) per-source drain limit; both are traced arguments so one
     compiled step serves a whole load sweep.  The ``jax`` step takes the
     route tables as a device-resident argument, never as constants of
-    the compiled program."""
+    the compiled program, and its dense planes made on the device
+    (:func:`repro.sim.tables.device_planes`); the numpy step reads the
+    host planes."""
     from .. import obs
     obs.counter(f"sim.step_build[{backend}]").add(1.0)
     spec = _DenseSpec(t.n, t.k, t.m, cfg.mode, cfg.threshold,
                       float(cfg.capacity), float(min(cfg.buffer, _BIG)),
                       bool(getattr(t, "faulted", False)),
                       np.dtype(dtype).name)
+    planes = device_planes(t, dtype, t.k) if backend == "jax" else None
     with obs.span("sim.step_tables"):
-        tabs = _dense_tables(t, dtype)
+        tabs = _dense_tables(t, dtype, planes)
     if backend != "jax":
         return functools.partial(_dense_step(np, spec), tabs)
     return BoundStep(_jax_dense_step(spec), tabs, scoped_x64=True)
@@ -260,9 +268,10 @@ def make_step(t: RouteTables, cfg: SimConfig, backend: str, dtype):
 class BoundStep:
     """A jitted step with its route tables on the device:
     ``step(state, inj, inj_cap) = jitted(tabs, state, inj, inj_cap)``.
-    The tables are placed once, at construction (span ``sim.table_put``,
-    counter ``sim.table_put_bytes``); :meth:`put` places a host state the
-    same way, and :meth:`zeros` makes a zero state there.  The step may
+    The host tables are placed once, at construction (span
+    ``sim.table_put``, counter ``sim.table_put_bytes``), and tables made
+    on the device are taken as they are; :meth:`put` places a host state
+    the same way, and :meth:`zeros` makes a zero state there.  The step may
     lay the out-slot axis of its queue tensors (q0, q1, q2) at ``pad``
     empty slots past the graph's K: :meth:`put` and :meth:`zeros` take a
     state at K and lay it out so, and :meth:`view` cuts it back.  With
@@ -277,11 +286,12 @@ class BoundStep:
         self.jitted = jitted
         self.pad = pad
         self._scope = x64 if scoped_x64 else contextlib.nullcontext
+        sent = sum(a.nbytes for a in jax.tree.leaves(tabs)
+                   if isinstance(a, np.ndarray))
         with self._scope(), obs.span("sim.table_put") as sp:
             self.tabs = jax.device_put(tabs)
             sp.sync(self.tabs)
-        obs.counter("sim.table_put_bytes").add(
-            float(sum(a.nbytes for a in jax.tree.leaves(self.tabs))))
+        obs.counter("sim.table_put_bytes").add(float(sent))
 
     def put(self, state):
         """Host ``state`` (a pytree of host arrays) on the step's device,

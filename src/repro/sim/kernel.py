@@ -68,10 +68,10 @@ from typing import NamedTuple
 import numpy as np
 
 from .engine import _BIG, _TINY, BoundStep, SimConfig, pad_slots
-from .tables import RouteTables
+from .tables import RouteTables, device_planes
 
 __all__ = ["make_step_sparse", "step_aux", "resolve_dtype", "laid_slots",
-           "SPARSE_BACKENDS"]
+           "step_planes", "SPARSE_BACKENDS"]
 
 SPARSE_BACKENDS = ("pallas", "pallas_interpret")
 
@@ -231,6 +231,24 @@ def laid_slots(k: int) -> int:
     state and the split and deliver tables around the kernels.  The
     extra slots are empty: head N, zero split and deliver, no fluid."""
     return -(-k // SUBLANE) * SUBLANE
+
+
+def step_planes(t: RouteTables, backend: str, dtype, dest_cols=None):
+    """The dense planes ``backend``'s step reads, made on the device at
+    the step's layout (:func:`repro.sim.tables.device_planes`, which
+    keeps them on ``t`` for the step's build): the graph's K for the
+    dense ``jax`` step, :func:`laid_slots` and the ``dest_cols`` axis for
+    the kernel step (``pallas_interpret``, and ``pallas`` on a TPU).
+    None for the numpy steps, which read the host planes of ``t``."""
+    if backend == "jax":
+        return device_planes(t, dtype, t.k)
+    if backend == "pallas":
+        import jax
+        if jax.default_backend() != "tpu":
+            return None
+    if backend in SPARSE_BACKENDS:
+        return device_planes(t, dtype, laid_slots(t.k), dest_cols)
+    return None
 
 
 def resolve_dtype(name: str, backend: str):
@@ -669,11 +687,13 @@ class _KernelSpec(NamedTuple):
     interpret: bool
 
 
-def _kernel_tables(t: RouteTables, dtype, dest_cols=None) -> dict:
-    """Host-side arrays the kernel step reads: the (N, K, W) split and
-    deliver tables of each dest axis, the (N, W) decision tables, and
-    the arc-index structure (int32), all with the out-slot axis laid at
-    :func:`laid_slots` and arcs numbered ``router * laid + slot``."""
+def _kernel_tables(t: RouteTables, dtype, planes, dest_cols=None) -> dict:
+    """The arrays the kernel step reads: the dense planes ``planes``
+    (:func:`repro.sim.tables.device_planes`: the (N, K, W) split and
+    deliver of each dest axis, ``w_val`` and ``spread``), the (N, W)
+    decision tables and the arc-index structure (int32), all with the
+    out-slot axis laid at :func:`laid_slots` and arcs numbered
+    ``router * laid + slot``."""
     aux = step_aux(t)
     n, k = t.n, t.k
     kl = laid_slots(k)
@@ -682,9 +702,8 @@ def _kernel_tables(t: RouteTables, dtype, dest_cols=None) -> dict:
     asd = lambda a: np.asarray(a, dtype=dtype)
     idx = lambda a: np.asarray(a, dtype=np.int32)
 
-    def axis_tables(ax, sel):
-        return {"split": pad_slots(t.split[:, :, sel], pad, dtype),
-                "deliver": pad_slots(t.deliver[:, :, sel], pad, dtype),
+    def axis_tables(ax, plane):
+        return {"split": plane["split"], "deliver": plane["deliver"],
                 "fix_arc": idx(lay(ax.fix_arc)), "fix_dst": idx(ax.fix_dst),
                 "fix_router": idx(ax.fix_router),
                 "dst_router": idx(ax.dst_router),
@@ -696,11 +715,9 @@ def _kernel_tables(t: RouteTables, dtype, dest_cols=None) -> dict:
     in_active = np.zeros(n, dtype=bool)
     in_active[t.active] = True
     tabs = {
-        "F": axis_tables(_DestAxis(aux), slice(None)),
+        "F": axis_tables(_DestAxis(aux), planes["F"]),
         "dist_c": asd(t.dist_act[:, sel]), "hval_c": asd(t.hval_rem[:, sel]),
-        "spread": asd(t.spread),
-        "w_val": pad_slots(np.einsum("nm,nkm->nk", t.spread, t.split), pad,
-                           dtype),
+        "spread": planes["spread"], "w_val": planes["w_val"],
         "spread_T": asd(t.spread.T), "n_mids": asd(t.m - in_active),
         "active": idx(t.active),
         "head_flat": pad_slots(t.head, pad, np.int32, fill=n).reshape(-1),
@@ -710,16 +727,18 @@ def _kernel_tables(t: RouteTables, dtype, dest_cols=None) -> dict:
         "diag_mid": idx(diag_mid), "diag_col": idx(diag_col),
     }
     if dest_cols is not None:
-        tabs["C"] = axis_tables(_DestAxis(aux, dest_cols), sel)
+        tabs["C"] = axis_tables(_DestAxis(aux, dest_cols), planes["C"])
     return tabs
 
 
 def kernel_program(t: RouteTables, cfg: SimConfig, dtype, interpret,
                    dest_cols=None):
     """``(jitted, tabs)``: the compiled-once kernel step
-    ``jitted(tabs, state, inj, inj_cap)`` and the host-side route tables
-    it takes as its first argument; its state's out-slot axis is laid at
-    :func:`laid_slots` of ``t.k``."""
+    ``jitted(tabs, state, inj, inj_cap)`` and the route tables it takes
+    as its first argument; its state's out-slot axis is laid at
+    :func:`laid_slots` of ``t.k``.  Its dense planes are made on the
+    device (:func:`repro.sim.tables.device_planes` at that layout); the
+    other tables are host arrays."""
     from .. import obs
     c = t.m if dest_cols is None else len(dest_cols)
     spec = _KernelSpec(t.n, laid_slots(t.k), (c, t.m, c),
@@ -728,8 +747,9 @@ def kernel_program(t: RouteTables, cfg: SimConfig, dtype, interpret,
                        float(min(cfg.buffer, _BIG)),
                        bool(getattr(t, "faulted", False)),
                        np.dtype(dtype).name, bool(interpret))
+    planes = device_planes(t, dtype, laid_slots(t.k), dest_cols)
     with obs.span("sim.step_tables"):
-        tabs = _kernel_tables(t, dtype, dest_cols)
+        tabs = _kernel_tables(t, dtype, planes, dest_cols)
     return _kernel_step(spec), tabs
 
 

@@ -296,7 +296,9 @@ def test_sim_byte_counters_match_moved_arrays(backend, enabled):
     final state there: no state bytes are sent, and one state comes back
     per run only where something reads ``last_state`` (an enabled
     session's balance statistics).  The tables are placed once per
-    build, also in a session with ``enabled = False``."""
+    build, also in a session with ``enabled = False``: the step's host
+    tables, and the inputs of the program that makes the dense planes
+    on the device, never the planes themselves."""
     import jax
     dem = _uniform(G16)
     with obs.session(mode="trace", series=False) as sess:
@@ -306,7 +308,16 @@ def test_sim_byte_counters_match_moved_arrays(backend, enabled):
         sim.run(dem, 0.3, steps=3)
         sim.run(dem, 0.4, steps=3)
     state = _state_bytes(sim)
-    tables = sum(a.nbytes for a in jax.tree.leaves(sim._step.tabs))
+    t, tabs = sim.tables, sim._step.tabs
+    full = tabs.get("F", tabs)              # the kernel step's full axis
+    made = [tabs["w_val"], full["split"], full["deliver"]]
+    slots = tabs["w_val"].shape[1]
+    # distances, laid head (int32) and slot mask, the K + 1 reciprocals
+    # and the dest ids (int32); the spread goes as an input and stays
+    inputs = (t.dist.nbytes + t.n * slots * 5
+              + (t.k + 1) * np.dtype(sim.dtype).itemsize + 4 * t.m)
+    tables = (sum(a.nbytes for a in jax.tree.leaves(tabs))
+              - sum(a.nbytes for a in made) + inputs)
     m = sess.metrics
     assert m.counter("sim.table_put_bytes").value == tables > 0
     assert m.counter("sim.state_put_bytes").value == 0
@@ -435,7 +446,8 @@ def test_sim_run_without_session_never_syncs(monkeypatch):
     assert calls == []
     with obs.session(mode="trace"):
         Simulator(G16, JAX_UGAL).run(_uniform(G16), 0.3, steps=4)
-    assert len(calls) == 2  # sim.table_put and sim.state_put
+    # sim.route_tables (the planes), sim.table_put and sim.state_put
+    assert len(calls) == 3
 
 
 def test_sim_spans_land_in_a_jax_profile(tmp_path):

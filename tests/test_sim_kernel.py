@@ -152,8 +152,9 @@ def test_sublane_multiple_degrees_get_no_padding():
     assert t.k == 32
     cfg = SimConfig(routing="ugal_threshold(0)")
     jitted, tabs = kernel_program(t, cfg, np.float32, interpret=True)
-    np.testing.assert_array_equal(tabs["F"]["split"], t.split)
-    np.testing.assert_array_equal(tabs["F"]["deliver"], t.deliver)
+    np.testing.assert_array_equal(np.asarray(tabs["F"]["split"]), t.split)
+    np.testing.assert_array_equal(np.asarray(tabs["F"]["deliver"]),
+                                  t.deliver)
     np.testing.assert_array_equal(tabs["head_flat"], t.head.reshape(-1))
     aux = step_aux(t)
     np.testing.assert_array_equal(tabs["F"]["fix_arc"], aux.fix_arc)

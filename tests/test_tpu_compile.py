@@ -192,3 +192,32 @@ def test_mms27_step_keeps_the_kernels_layout(one_chip):
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes)
     assert 0 < total < HBM_BYTES, total
+
+
+PLANE_CASES = [(1986, 32, (1986, 993)), (1986, 32, (1986,)),
+               (1458, 41, (1458,)), (1106, 24, (1106,))]
+
+
+@pytest.mark.parametrize("case", PLANE_CASES,
+                         ids=["pn31_points", "pn31", "mms27", "pn23"])
+def test_plane_program_compiles_within_hbm(one_chip, case):
+    """The program that makes the route tables' dense planes on the
+    device, at the cells' sizes: PN(31) with its dest axis also
+    compacted to the 993 points, PN(31) and MMS(27) whole at the kernel
+    step's laid out-slots, PN(23) at its K = 24.  It compiles
+    for the chip and, with its outputs, fits one chip's memory."""
+    from repro.sim.kernel import laid_slots
+    from repro.sim.tables import _plane_program
+    n, k, widths = case
+    kl = laid_slots(k)
+    i32 = lambda *sh: _spec(one_chip, sh, np.int32)
+    args = (i32(n, n), i32(n, kl), _spec(one_chip, (n, kl), np.bool_),
+            _spec(one_chip, (n, n)), _spec(one_chip, (k + 1,)),
+            tuple(i32(w) for w in widths))
+    compiled = _plane_program().lower(*args).compile()
+    mem = compiled.memory_analysis()
+    planes = sum(2 * 4 * n * kl * w for w in widths)
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes)
+    assert mem.output_size_in_bytes >= planes
+    assert 0 < total < HBM_BYTES, total
