@@ -129,19 +129,16 @@ class _blas_limit:
         return False
 
 
-def _run_units(fns, workers: int | None = None):
+def _run_units(fns):
     """Run independent work units, threaded when util_workers allows.
 
     numpy releases the GIL inside GEMMs and ufunc loops, so two
     single-BLAS-thread sweeps overlap almost perfectly on two cores.
     Exceptions (e.g. the disconnected-graph ValueError) re-raise in the
-    caller.  ``workers`` overrides the util_workers flag — the fused sim
-    step (repro.sim.kernel) reuses this wave loop under its own
-    sim_workers flag."""
+    caller."""
     import threading
 
-    if workers is None:
-        workers = flags().util_workers
+    workers = flags().util_workers
     if len(fns) <= 1 or workers <= 1:
         return [f() for f in fns]
     results = [None] * len(fns)

@@ -24,10 +24,13 @@ The dest axis is *blocked-sparse*: ``tile_mask`` (one int32 per dest
 tile, scalar-prefetched) marks the populated tiles; unpopulated tiles —
 zero fluid and zero inflow, so the contraction is identically zero —
 are skipped under ``pl.when`` and only pay the (clipped) output write.
-This is the kernel seam behind ``SimConfig(backend="pallas")``; the
-numpy float64 engine remains the parity oracle and
-``backend="pallas_interpret"`` runs this exact kernel through the
-pallas interpreter on CPU (tests/test_sim_kernel.py).
+This is the kernel seam behind ``SimConfig(backend="pallas")``.  Each
+kernel takes an ``impl`` in the vocabulary of :mod:`repro.kernels.ops`:
+``"pallas"`` (the Mosaic kernel, TPU only), ``"pallas_interpret"`` (the
+same kernel through the pallas interpreter) or ``"jnp"`` (the kernel's
+own formula on whole arrays, which XLA compiles on any platform).  The
+dense numpy float64 engine remains the parity oracle
+(tests/test_sim_kernel.py).
 
 Block structure follows flash_attention.py / ssd_scan.py.  The router
 block is chosen from K (:func:`router_block`) so the pipelined blocks fit
@@ -44,12 +47,13 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["fused_step_update", "fused_decision", "router_block",
-           "DEST_TILE"]
+           "DEST_TILE", "IMPLS"]
 
-# dest-tile width: the TPU lane dimension; also the block the numpy
-# fused path (repro.sim.kernel) uses so both backends skip identical
-# (router, dest-tile) blocks
+# dest-tile width: the TPU lane dimension; the kernel step
+# (repro.sim.kernel) sums its tile masks at the same width
 DEST_TILE = 128
+
+IMPLS = ("pallas", "pallas_interpret", "jnp")
 
 # share of the default scoped VMEM (16 MiB on TPU v5e) the pipelined
 # blocks of one grid step may take; the rest holds the kernel's
@@ -98,9 +102,20 @@ def _kernel(mask_ref, q_ref, split_ref, deliver_ref, fac_ref, corr_ref,
         qout_ref[...] = jnp.zeros_like(qout_ref)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+def _check_impl(impl: str):
+    if impl not in IMPLS:
+        raise ValueError(f"unknown sim kernel impl {impl!r}; options: "
+                         f"{IMPLS}")
+
+
+# The "jnp" bodies below ignore tile_mask: a masked tile holds zero
+# fluid, zero inflow and zero candidates, so both formulas give zero
+# there, as the kernels' skipped blocks do.
+
+
+@functools.partial(jax.jit, static_argnames=("impl",))
 def fused_step_update(q, split, deliver, fac, corr, inflow, tile_mask,
-                      interpret: bool = False):
+                      impl: str = "pallas"):
     """One VC's fused forward/throttle/enqueue update.
 
     Args:
@@ -115,6 +130,11 @@ def fused_step_update(q, split, deliver, fac, corr, inflow, tile_mask,
     Returns ``(q_out, o_out)``: the updated queues and the per-slot
     post-step occupancy ``q_out.sum(-1)``.
     """
+    _check_impl(impl)
+    if impl == "jnp":
+        upd = (q * fac[:, :, None] - q * corr[:, :, None] * deliver
+               + inflow[:, None, :] * split)
+        return upd, upd.sum(axis=-1)
     n, k, m = q.shape
     bn = min(router_block(k, q.dtype.itemsize), n)
     bd = min(DEST_TILE, m)
@@ -134,7 +154,7 @@ def fused_step_update(q, split, deliver, fac, corr, inflow, tile_mask,
         ),
         out_shape=[jax.ShapeDtypeStruct((n, k, m), q.dtype),
                    jax.ShapeDtypeStruct((n, k), q.dtype)],
-        interpret=interpret,
+        interpret=impl == "pallas_interpret",
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
     )(jnp.asarray(tile_mask, jnp.int32), q, split, deliver, fac, corr,
@@ -160,9 +180,9 @@ def _decision_kernel(mask_ref, b0_ref, split_ref, dist_ref, hval_ref,
         out_ref[...] = jnp.zeros_like(out_ref)
 
 
-@functools.partial(jax.jit, static_argnames=("thr", "interpret"))
+@functools.partial(jax.jit, static_argnames=("thr", "impl"))
 def fused_decision(b0, split, dist, hval, cand, q_val, tile_mask,
-                   thr: float, interpret: bool = False):
+                   thr: float, impl: str = "pallas"):
     """The per-hop UGAL decision as one blocked pass: divert candidates.
 
     Folds the ``q_min = einsum("nk,nkm->nm", b0, split)`` backlog gather
@@ -188,6 +208,12 @@ def fused_decision(b0, split, dist, hval, cand, q_val, tile_mask,
     false for ``thr >= 0``), so a partial last tile's block padding is
     discarded by the clipped write-back, exactly as in the step kernel.
     """
+    _check_impl(impl)
+    if impl == "jnp":
+        q_min = jnp.einsum("nk,nkm->nm", b0, split,
+                           precision=jax.lax.Precision.HIGHEST)
+        divert = dist * q_min > thr + hval * q_val[:, None]
+        return jnp.where(divert, cand, 0.0)
     n, k, m = split.shape
     bn = min(router_block(k, split.dtype.itemsize), n)
     bd = min(DEST_TILE, m)
@@ -207,7 +233,7 @@ def fused_decision(b0, split, dist, hval, cand, q_val, tile_mask,
             out_specs=nd,
         ),
         out_shape=jax.ShapeDtypeStruct((n, m), cand.dtype),
-        interpret=interpret,
+        interpret=impl == "pallas_interpret",
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
     )(jnp.asarray(tile_mask, jnp.int32), b0, split, dist, hval, cand,

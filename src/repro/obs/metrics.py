@@ -28,10 +28,9 @@ class Counter:
     """Monotone accumulator (``add``); float-valued so fluid mass and
     call counts share one type.
 
-    Mutation takes a per-metric lock: the threaded CPU slab loop
-    (``perf.flags().sim_workers > 1``) can publish wave telemetry from
-    worker threads, and ``self.value += v`` is a read-modify-write that
-    loses increments under free-threaded interleaving.  The lock only
+    Mutation takes a per-metric lock: threads may publish to one
+    counter, and ``self.value += v`` is a read-modify-write that loses
+    increments under free-threaded interleaving.  The lock only
     costs when a session is active (obs off hands out NULL_METRIC)."""
 
     __slots__ = ("name", "value", "_lock")

@@ -62,11 +62,6 @@ paper tables on the reference implementation.
 Flow-level simulator flags (repro.sim):
   sim_backend=NAME — default backend for ``SimConfig(backend="auto")``:
                   auto | numpy | jax | pallas | pallas_interpret.
-  sim_workers=N — Python threads over independent (vc, dest-tile) slab
-                  updates inside the fused numpy sim step (waves, like
-                  util_workers; numpy releases the GIL in the slab
-                  ufuncs).  Bitwise deterministic at any N — slabs write
-                  disjoint output columns.  1 = sequential.
 
 Observability (repro.obs):
   obs=MODE      — default mode for ``obs.session()`` calls that do not
@@ -140,19 +135,13 @@ class PerfFlags:
     # Flow-level simulator backend (repro.sim): auto | numpy | jax |
     # pallas | pallas_interpret.  auto picks the jit-compiled jax step
     # for large (N * degree * dests) instances, the numpy reference
-    # otherwise, and the fused blocked sparse-dest step (repro.sim.kernel
-    # — the pallas kernel on TPU, its blocked numpy mirror on CPU) once
-    # the dense cell count exceeds engine.SIM_MAX_CELLS; pallas_interpret
-    # runs the actual kernel through the pallas interpreter (parity
-    # testing).  SimConfig(backend=...) overrides per run.
+    # otherwise, and the fused blocked sparse-dest kernel step
+    # (repro.sim.kernel — the pallas kernels on TPU, their jnp bodies
+    # elsewhere) once the dense cell count exceeds engine.SIM_MAX_CELLS;
+    # pallas_interpret runs the actual kernels through the pallas
+    # interpreter (parity testing).  SimConfig(backend=...) overrides per
+    # run.
     sim_backend: str = "auto"
-    # Python threads running independent (vc, dest-tile) slab updates
-    # inside the fused numpy sim step (repro.sim.kernel) — the
-    # util_workers wave idiom one layer down.  Slab outputs are disjoint
-    # column ranges, so the result is bitwise identical at any worker
-    # count; threading engages only past a live-cell threshold so tiny
-    # instances keep the serial path.  1 = sequential.
-    sim_workers: int = 2
     # Observability default mode for repro.obs sessions opened without an
     # explicit mode: none (off — every span/counter helper returns a
     # shared no-op singleton, the hot paths pay one global read), metrics

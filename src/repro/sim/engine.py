@@ -98,9 +98,9 @@ class SimConfig:
     the offered quantum so a backlogged source cannot flood the fabric in
     one step; ``backend`` is ``auto`` / ``numpy`` / ``jax`` /
     ``pallas`` / ``pallas_interpret`` (the fused blocked sparse-dest
-    step of repro.sim.kernel — the pallas kernel on TPU, the same
-    blocked structure in numpy on CPU, or the kernel under the pallas
-    interpreter); ``dtype`` is the state dtype — ``auto`` (float64 for
+    step of repro.sim.kernel — the pallas kernels on TPU, their jnp
+    formulas elsewhere, or the kernels under the pallas interpreter);
+    ``dtype`` is the state dtype — ``auto`` (float64 for
     the dense backends, float32 for the fused ones), ``float32``, or
     ``float64``; ``compact`` gates static dest compaction against the
     run's demand matrix — ``auto`` (default: shrink the active set under

@@ -1,17 +1,17 @@
 """The fused sparse-destination step kernel seam (repro.sim.kernel +
 repro.kernels.sim_step/mask_gemm) and the PR's sim-reporting fixes.
 
-Parity contract: the dense numpy float64 engine is the oracle.
-``backend="pallas"`` on CPU runs the same blocked sparse-dest algebra in
-numpy (bit-level comparable at float64); ``backend="pallas_interpret"``
-runs the actual pallas kernel through the interpreter — same fluid, TPU
-summation order, so float64 agreement to round-off.  Dest compaction
-must be EXACT in both shapes: the minimal-mode active-set shrink, and
-the ugal/valiant per-VC compacted dest axis (q0/q2/src/pend-dest on the
+Parity contract: the dense numpy float64 engine is the oracle.  Both
+sparse backends run the one jitted kernel step the TPU runs;
+``backend="pallas"`` off a TPU gives its two kernels their ``jnp``
+bodies (the kernels' own formulas on whole arrays, round-off-level
+identity at float64), and ``backend="pallas_interpret"`` runs the actual
+pallas kernels through the interpreter — same fluid, TPU summation
+order, so float64 agreement to round-off.  Dest compaction must be
+EXACT in both shapes: the minimal-mode active-set shrink, and the
+ugal/valiant per-VC compacted dest axis (q0/q2/src/pend-dest on the
 demanded columns, q1/stage2 on the full mid axis) — dropping
 never-addressed dest columns is a reindexing, not an approximation.
-The fused UGAL decision and the sim_workers threaded slab loop must be
-bitwise identical to their serial dense counterparts.
 
 The reporting regressions pinned here:
   * run histories are normalized per fault segment (a pre-event curve
@@ -72,7 +72,7 @@ def _run_backend(g, demand, backend, routing="minimal", offered=0.5,
 
 
 # ---------------------------------------------------------------------------
-# numpy vs pallas step parity
+# dense reference vs kernel step parity
 # ---------------------------------------------------------------------------
 
 
@@ -81,9 +81,10 @@ def _run_backend(g, demand, backend, routing="minimal", offered=0.5,
     [(G16, r) for r in ROUTINGS]
     + [(g, r) for g in (MMS5, MMS7) for r in ROUTINGS],
     ids=ROUTINGS + [f"{g}-{r}" for g in ("mms5", "mms7") for r in ROUTINGS])
-def test_fused_numpy_matches_dense_float64(g, routing):
-    """The CPU 'pallas' backend (blocked sparse-dest numpy) against the
-    dense oracle, all routing modes, float64: round-off-level identity."""
+def test_fused_step_matches_dense_float64(g, routing):
+    """The 'pallas' backend off a TPU (the kernel step with its kernels'
+    jnp bodies) against the dense oracle, all routing modes, float64:
+    round-off-level identity."""
     dem = _uniform(g)
     offered = 0.7 * g.max_degree / G16.max_degree
     a = _run_backend(g, dem, "numpy", routing, offered=offered)
@@ -151,7 +152,7 @@ def test_sublane_multiple_degrees_get_no_padding():
     t = build_tables(g, np.arange(n), dtype=np.float32)
     assert t.k == 32
     cfg = SimConfig(routing="ugal_threshold(0)")
-    jitted, tabs = kernel_program(t, cfg, np.float32, interpret=True)
+    jitted, tabs = kernel_program(t, cfg, np.float32, "pallas_interpret")
     np.testing.assert_array_equal(np.asarray(tabs["F"]["split"]), t.split)
     np.testing.assert_array_equal(np.asarray(tabs["F"]["deliver"]),
                                   t.deliver)
@@ -228,31 +229,11 @@ def test_compacted_run_rejects_foreign_demand():
         sim.run(other, 0.5, 8)
 
 
-def test_sim_workers_bitwise_deterministic(monkeypatch):
-    """Slab units write disjoint output column ranges: any sim_workers
-    count must produce bit-identical histories (threshold forced to 0 so
-    the small fixture actually threads)."""
-    import repro.sim.kernel as K
-    from repro.perf import flags
-    monkeypatch.setattr(K, "SIM_THREAD_MIN_CELLS", 0)
-    dem = _random_demand(G16, 3)
-    out = {}
-    for w in (1, 4):
-        monkeypatch.setattr(flags(), "sim_workers", w)
-        out[w] = _run_backend(G16, dem, "pallas", "ugal_threshold(0)",
-                              offered=0.7, buffer=6.0)
-    for key in ("delivered", "accepted", "offered", "occupancy",
-                "src_backlog", "diverted"):
-        np.testing.assert_array_equal(
-            out[1].history[key], out[4].history[key],
-            err_msg=f"history[{key!r}] not bitwise equal across workers")
-
-
 def test_fused_decision_interior_blend_parity():
     """torus2d_8x16 tornado at ugal_threshold(0): the blend optimum is
     interior (0 < alpha < 1), so both branches of the fused decision —
-    divert and keep — carry fluid.  Blocked fused decision vs the dense
-    einsum decision, float64."""
+    divert and keep — carry fluid.  The kernel step's fused decision (its
+    jnp body off a TPU) vs the dense einsum decision, float64."""
     g = torus3d_graph(8, 16, 1)
     dem = normalize_demand(make_pattern("tornado").demand(g, None))
     a = _run_backend(g, dem, "numpy", "ugal_threshold(0)", offered=0.38,
@@ -373,7 +354,7 @@ def test_kernel_step_program_holds_no_tables():
     g = pn_graph(16)
     t = build_tables(g, np.arange(g.n), dtype=np.float32)
     jitted, tabs = kernel_program(t, SimConfig(routing="ugal_threshold(0)"),
-                                  np.float32, interpret=True)
+                                  np.float32, "pallas_interpret")
     # the program takes its state laid out: K = 17 at 24 out-slots
     pad = laid_slots(t.k) - t.k
     state = tuple(pad_slots(a, pad) if a.ndim == 3 else a
@@ -444,13 +425,113 @@ def test_fault_states_share_one_compile(backend):
 
 def test_pallas_float64_on_tpu_raises(monkeypatch):
     """On a TPU the pallas kernel runs float32; float64 is refused by
-    name instead of being compiled (or quietly interpreted)."""
+    name instead of being compiled (or quietly interpreted), and float32
+    builds the kernel step with the Mosaic kernels (``impl="pallas"``)."""
     import jax
+
+    import repro.sim.kernel as K
+    from repro import obs
+    from repro.sim.tables import build_tables
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     cfg = SimConfig(routing="ugal_threshold(0)", backend="pallas",
                     dtype="float64")
     with pytest.raises(ValueError, match="float32 on TPU"):
         Simulator(G16, cfg, demand=_uniform(G16))
+    built = []
+    monkeypatch.setattr(K, "_make_step_kernel",
+                        lambda t, cfg, dtype, impl, dest_cols=None:
+                        built.append(impl))
+    t = build_tables(G16, np.arange(G16.n), dtype=np.float32)
+    with obs.session(mode="metrics") as sess:
+        K.make_step_sparse(t, SimConfig(routing="ugal_threshold(0)"),
+                           "pallas", np.float32)
+    assert built == ["pallas"]
+    assert sess.metrics.counter("sim.step_build[pallas_tpu]").value == 1.0
+
+
+def test_pallas_off_tpu_runs_the_kernel_step_with_jnp_bodies(monkeypatch):
+    """Off a TPU, ``backend="pallas"`` builds the one kernel step program
+    (``_kernel_step``) with ``impl="jnp"``, and its build counter is the
+    only ``sim.step_build[pallas_*]`` counter the session records."""
+    import jax
+
+    import repro.sim.kernel as K
+    from repro import obs
+    assert jax.default_backend() != "tpu"
+    specs, programs = [], []
+    real = K._kernel_step
+
+    def spy(spec):
+        specs.append(spec)
+        programs.append(real(spec))
+        return programs[-1]
+
+    monkeypatch.setattr(K, "_kernel_step", spy)
+    dem = _uniform(G16)
+    with obs.session(mode="metrics") as sess:
+        sim = Simulator(G16, SimConfig(routing="ugal_threshold(0)",
+                                       backend="pallas"), demand=dem)
+        sim.run(dem, 0.5, 4)
+    assert [s.impl for s in specs] == ["jnp"]
+    assert sim._step.jitted is programs[0]
+    builds = sorted(n for n in sess.metrics.names()
+                    if n.startswith("sim.step_build[pallas"))
+    assert builds == ["sim.step_build[pallas_jnp]"]
+
+
+def _kernel_case(k, m, masked, seed=0):
+    """Random float64 inputs of both sim kernels at (N=16, K, M).  The
+    out-slots past ``k`` of a K laid at 48 are empty (no fluid, zero
+    split and deliver, no backlog), as the kernel step lays them; a
+    ``masked`` tile holds no fluid, inflow or candidates."""
+    from repro.kernels.sim_step import DEST_TILE
+    rng = np.random.default_rng(seed)
+    n, kl = 16, -(-k // 8) * 8
+    u = lambda *sh: rng.random(sh)
+    q, split, deliver = u(n, kl, m), u(n, kl, m), u(n, kl, m) < 0.1
+    split[:, k:] = deliver[:, k:] = q[:, k:] = 0.0
+    fac, corr, b0 = u(n, kl), u(n, kl), u(n, kl)
+    b0[:, k:] = 0.0
+    inflow, dist, hval, cand = u(n, m), u(n, m), u(n, m), u(n, m)
+    q_val = u(n)
+    b0[0] = 0.0                                # a row that never diverts
+    mask = np.ones(-(-m // DEST_TILE), np.int32)
+    for j in masked:
+        mask[j] = 0
+        cols = slice(j * DEST_TILE, (j + 1) * DEST_TILE)
+        q[:, :, cols] = inflow[:, cols] = cand[:, cols] = 0.0
+    upd = (q, split, deliver.astype(np.float64), fac, corr, inflow, mask)
+    dec = (b0, split, dist, hval, cand, q_val, mask)
+    return upd, dec
+
+
+@pytest.mark.parametrize("k,m,masked", [(8, 256, ()), (8, 200, ()),
+                                        (8, 384, (1,)), (41, 300, ())],
+                         ids=["full_tiles", "partial_last_tile",
+                              "masked_tile", "k41_laid_at_48"])
+def test_jnp_kernel_bodies_match_interpreted_kernels(k, m, masked):
+    """Both sim kernels' ``jnp`` bodies against the pallas kernels run by
+    the interpreter, float64: whole tiles, a partial last tile, a tile
+    the mask skips, and K = 41 laid at 48 out-slots."""
+    from repro.jaxenv import x64
+    from repro.kernels.sim_step import (DEST_TILE, fused_decision,
+                                        fused_step_update)
+    upd, dec = _kernel_case(k, m, masked)
+    with x64():
+        out = {impl: (fused_step_update(*upd, impl=impl),
+                      fused_decision(*dec, thr=0.25, impl=impl))
+               for impl in ("jnp", "pallas_interpret")}
+    (qj, oj), dj = out["jnp"]
+    (qi, oi), di = out["pallas_interpret"]
+    assert qj.dtype == qi.dtype == np.float64
+    np.testing.assert_allclose(qj, qi, rtol=1e-12, atol=1e-14)
+    np.testing.assert_allclose(oj, oi, rtol=1e-12, atol=1e-12)
+    np.testing.assert_array_equal(dj, di)
+    assert 0 < np.count_nonzero(dj) < dj.size   # both decision branches
+    for j in masked:
+        cols = slice(j * DEST_TILE, (j + 1) * DEST_TILE)
+        assert not np.asarray(qj)[:, :, cols].any()
+        assert not np.asarray(dj)[:, cols].any()
 
 
 # ---------------------------------------------------------------------------

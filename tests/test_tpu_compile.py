@@ -62,7 +62,7 @@ def test_fused_step_update_compiles(one_chip, shape):
     s = lambda *sh: _spec(one_chip, sh)
     args = (s(n, k, m), s(n, k, m), s(n, k, m), s(n, k), s(n, k), s(n, m),
             _spec(one_chip, (-(-m // DEST_TILE),), np.int32))
-    compiled = fused_step_update.lower(*args).compile()
+    compiled = fused_step_update.lower(*args, impl="pallas").compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
@@ -73,7 +73,8 @@ def test_fused_decision_compiles(one_chip, shape):
     s = lambda *sh: _spec(one_chip, sh)
     args = (s(n, k), s(n, k, m), s(n, m), s(n, m), s(n, m), s(n),
             _spec(one_chip, (-(-m // DEST_TILE),), np.int32))
-    compiled = fused_decision.lower(*args, thr=0.0).compile()
+    compiled = fused_decision.lower(*args, thr=0.0,
+                                    impl="pallas").compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
@@ -108,7 +109,7 @@ def test_kernel_step_compiles_within_hbm(one_chip, q):
     t = build_tables(g, np.arange(g.n), dtype=np.float32)
     cols = None if q == 16 else np.arange(q * q + q + 1)
     cfg = SimConfig(routing="ugal_threshold(0)")
-    jitted, tabs = kernel_program(t, cfg, np.float32, interpret=False,
+    jitted, tabs = kernel_program(t, cfg, np.float32, "pallas",
                                   dest_cols=cols)
     # the state as the step lays it out (K rounded up to 8)
     pad = laid_slots(t.k) - t.k
@@ -174,12 +175,12 @@ def test_mms27_step_keeps_the_kernels_layout(one_chip):
     cfg = SimConfig(routing="ugal_threshold(0)")
     # the same tables as the program builds them, at MMS(5)'s size
     t5 = build_tables(mms_graph(5), np.arange(50), dtype=np.float32)
-    _, tabs5 = kernel_program(t5, cfg, np.float32, interpret=False)
+    _, tabs5 = kernel_program(t5, cfg, np.float32, "pallas")
     assert jax.tree.structure(tabs5) == jax.tree.structure(tabs)
     assert ([(a.ndim, a.dtype) for a in jax.tree.leaves(tabs5)]
             == [(a.ndim, a.dtype) for a in jax.tree.leaves(tabs)])
     spec = _KernelSpec(n, kl, (m, m, m), False, cfg.mode, cfg.threshold,
-                       1.0, 1e12, False, "float32", False)
+                       1.0, 1e12, False, "float32", "pallas")
     assert kl == 48
     compiled = _kernel_step(spec).lower(tabs, state, f32(n, m),
                                         f32(n)).compile()
