@@ -309,7 +309,7 @@ def _kernel_tables(t: RouteTables, dtype, planes, dest_cols=None) -> dict:
         "spread_T": asd(t.spread.T), "n_mids": asd(t.m - in_active),
         "active": idx(t.active),
         "head_flat": pad_slots(t.head, pad, np.int32, fill=n).reshape(-1),
-        # reverse-arc gather: sentinel -> the appended zero row
+        # reverse-arc gather: N·K marks a slot with no partner arc
         "rev": pad_slots(np.where(aux.rev >= 0, lay(aux.rev),
                                   nk).reshape(n, k), pad, np.int32, fill=nk),
         "diag_mid": idx(diag_mid), "diag_col": idx(diag_col),
@@ -349,6 +349,22 @@ def _make_step_kernel(t: RouteTables, cfg: SimConfig, dtype, impl,
                      pad=laid_slots(t.k) - t.k)
 
 
+def _gather_arrivals(qs, share, rev):
+    """Each VC's arrivals over reverse arcs, read straight from its
+    (N, K, W) queues: ``a[r] = sum_k share[rev[r, k]] * q[rev[r, k]]``.
+    A sentinel arc (``rev == N·K``: an out-slot with no arc, laid past K
+    or past its router's degree) weighs 0, and its row is clamped to a
+    real one, which then adds 0 times a finite queue value."""
+    import jax
+    import jax.numpy as jnp
+    nk = rev.size
+    with jax.named_scope("forward_gather"):
+        weight = jnp.concatenate([share, jnp.zeros(1, share.dtype)])[rev]
+        row = jnp.minimum(rev, nk - 1).reshape(-1)            # (N·K,)
+        return [(q.reshape(nk, -1)[row].reshape(q.shape)
+                 * weight[:, :, None]).sum(axis=1) for q in qs]
+
+
 @functools.lru_cache(maxsize=16)
 def _kernel_step(spec: _KernelSpec):
     """The jitted ``step(tabs, state, inj, inj_cap)`` of one spec; the
@@ -385,7 +401,6 @@ def _kernel_step(spec: _KernelSpec):
         spread, w_val = tabs["spread"], tabs["w_val"]
         spread_T, n_mids = tabs["spread_T"], tabs["n_mids"]
         active, head_flat = tabs["active"], tabs["head_flat"]
-        rev = tabs["rev"]
         diag_mid, diag_col = tabs["diag_mid"], tabs["diag_col"]
         q0, q1, q2, src, pend, stage2 = state
         qs = (q0, q1, q2)
@@ -395,13 +410,8 @@ def _kernel_step(spec: _KernelSpec):
 
         arr, dl_sum, s_v, damp = [], [], [], []
         stage2_new = stage2
-        for v, q in enumerate(qs):
-            axis, w = ax[v], widths[v]
-            zrow = jnp.zeros((1, w), dtype=q0.dtype)
-            with jax.named_scope("forward_gather"):
-                mv = jnp.concatenate([q.reshape(nk, w) * share[:, None],
-                                      zrow])
-                a = mv[rev.reshape(-1)].reshape(n, k, w).sum(axis=1)
+        for v, a in enumerate(_gather_arrivals(qs, share, tabs["rev"])):
+            axis = ax[v]
             dl = a[axis["dst_router"], axis["dst_col"]]
             if v == 1:
                 stage2_new = stage2_new.at[axis["dst_col"]].add(dl)

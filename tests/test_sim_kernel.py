@@ -344,10 +344,9 @@ def test_dense_backend_above_cap_names_the_escape_hatch():
 # ---------------------------------------------------------------------------
 
 
-def test_kernel_step_program_holds_no_tables():
-    """The lowered pn16 kernel step takes the route tables as an
-    argument: its text stays far below the ~86 MB it had when the
-    (546, 17, 546) tables were inlined as literals."""
+def _pn16_kernel_step_text():
+    """``(text, N·K, M)`` of the lowered pn16 kernel step, which takes
+    its state laid out: K = 17 at 24 out-slots, so N·K counts 24."""
     from repro.sim.engine import init_state, pad_slots
     from repro.sim.kernel import kernel_program, laid_slots
     from repro.sim.tables import build_tables
@@ -355,13 +354,85 @@ def test_kernel_step_program_holds_no_tables():
     t = build_tables(g, np.arange(g.n), dtype=np.float32)
     jitted, tabs = kernel_program(t, SimConfig(routing="ugal_threshold(0)"),
                                   np.float32, "pallas_interpret")
-    # the program takes its state laid out: K = 17 at 24 out-slots
     pad = laid_slots(t.k) - t.k
     state = tuple(pad_slots(a, pad) if a.ndim == 3 else a
                   for a in init_state(t, np.float32).as_tuple())
     text = jitted.lower(tabs, state, np.zeros((t.n, t.m), np.float32),
                         np.zeros(t.n, np.float32)).as_text()
+    return text, t.n * laid_slots(t.k), t.m
+
+
+def test_kernel_step_program_holds_no_tables():
+    """The lowered pn16 kernel step takes the route tables as an
+    argument: its text stays far below the ~86 MB it had when the
+    (546, 17, 546) tables were inlined as literals."""
+    text, _, _ = _pn16_kernel_step_text()
     assert len(text) < 1_000_000
+
+
+def test_kernel_step_gathers_arrivals_without_a_zero_row_copy():
+    """The lowered pn16 kernel step makes no (N·K + 1, M) array by
+    concatenate or pad: the arrival gather reads the queues themselves,
+    not a share-scaled copy with a zero row appended for sentinel
+    arcs."""
+    text, nk, m = _pn16_kernel_step_text()
+    ops = [line for line in text.splitlines()
+           if "stablehlo.concatenate" in line or "stablehlo.pad" in line]
+    assert ops, "the step has no concatenate or pad at all"
+    assert not [op for op in ops
+                if f"-> tensor<{nk + 1}x{m}x" in op], f"({nk + 1}, {m})"
+
+
+def _old_arrival_gather(q, share, rev):
+    """The arrival gather as the kernel step made it before: the queues
+    scaled by each arc's share, a zero row appended for the sentinel
+    index N·K, gathered over reverse arcs and summed over K."""
+    import jax.numpy as jnp
+    n, k = rev.shape
+    w = q.shape[-1]
+    mv = jnp.concatenate([q.reshape(n * k, w) * share[:, None],
+                          jnp.zeros((1, w), q.dtype)])
+    return mv[rev.reshape(-1)].reshape(n, k, w).sum(axis=1)
+
+
+@pytest.mark.parametrize("dtype,rtol", [(np.float64, 1e-12),
+                                        (np.float32, 1e-6)],
+                         ids=["f64", "f32"])
+@pytest.mark.parametrize("faulted", [False, True],
+                         ids=["pn16", "pn16_links_down"])
+def test_arrival_gather_matches_the_zero_row_gather(faulted, dtype, rtol):
+    """The kernel step's arrival gather (per-arc weights, clamped rows)
+    against the zero-row gather it replaced, on PN(16)'s reverse-arc
+    table as the step lays it (K = 17 at 24, so 7 padded slots a router)
+    and on PN(16) with three links taken out of the graph (six routers
+    of degree 16, a sentinel inside the graph's K); random queues on
+    every slot, padded ones too, and random shares."""
+    import jax
+
+    from repro.jaxenv import x64
+    from repro.sim.kernel import _gather_arrivals, kernel_program, laid_slots
+    from repro.sim.tables import build_tables
+    g = pn_graph(16)
+    if faulted:
+        g = random_faults(g, k_links=3, seed=0).apply(g)
+    t = build_tables(g, np.arange(g.n), dtype=np.float32)
+    _, tabs = kernel_program(t, SimConfig(routing="ugal_threshold(0)"),
+                             np.float32, "jnp")
+    rev = np.asarray(tabs["rev"])
+    n, kl = rev.shape
+    assert (t.k, kl) == (17, 24)
+    assert (rev[:, t.k:] == n * kl).all()
+    assert (rev[:, :t.k] == n * kl).sum() == (6 if faulted else 0)
+    rng = np.random.default_rng(19)
+    qs = [rng.random((n, kl, w)).astype(dtype) for w in (t.m, 100)]
+    share = rng.uniform(0.05, 1.0, n * kl).astype(dtype)
+    with x64():
+        new = jax.jit(_gather_arrivals)(qs, share, rev)
+        old = [jax.jit(_old_arrival_gather)(q, share, rev) for q in qs]
+    for a, b in zip(new, old):
+        assert a.dtype == dtype
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=rtol, atol=0)
 
 
 @pytest.mark.parametrize("backend", ["pallas_interpret", "jax"])

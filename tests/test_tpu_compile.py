@@ -189,6 +189,8 @@ def test_mms27_step_keeps_the_kernels_layout(one_chip):
     big = re.compile(rf"f32\[{n},{kl},{m}\]{{(\d,\d,\d)[:}}]")
     assert set(big.findall(text)) == {"2,1,0"}
     assert _moves(text, f"f32[{n},{kl},{m}]") == []
+    # the arrival gather reads the queues: no zero-row-padded copy
+    assert f"f32[{n * kl + 1},{m}]" not in text
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes)
